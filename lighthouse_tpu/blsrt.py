@@ -336,6 +336,12 @@ class DevicePubkeyTable:
     def capacity(self) -> int:
         return self._cap
 
+    @classmethod
+    def capacity_for(cls, n: int) -> int:
+        """Rows on device (the table planes' leading dimension) once
+        ``n`` keys are registered."""
+        return max(cls.MIN_CAPACITY, next_pow2(n))
+
     def append_pubkeys(self, pubkeys) -> None:
         """Append oracle PublicKey objects (affine, validated non-infinity).
 
@@ -352,7 +358,7 @@ class DevicePubkeyTable:
             raise ValueError("infinity pubkey cannot enter the table")
         n_new = self._n + len(pts)
         if n_new > self._cap:
-            self._cap = max(self.MIN_CAPACITY, next_pow2(n_new))
+            self._cap = self.capacity_for(n_new)
             grown_x = np.zeros((self._cap, 48), np.uint8)
             grown_y = np.zeros((self._cap, 48), np.uint8)
             grown_x[: self._n] = self._host_x[: self._n]
@@ -449,7 +455,7 @@ def build_sequential_table(n: int, chunk: int = 8192) -> DevicePubkeyTable:
     from .ops.tkernel_calls import scalar_mul_g1_t, to_affine_g1_t
 
     table = DevicePubkeyTable()
-    table._cap = max(DevicePubkeyTable.MIN_CAPACITY, next_pow2(n))
+    table._cap = DevicePubkeyTable.capacity_for(n)
     table._host_x = np.zeros((table._cap, 48), np.uint8)
     table._host_y = np.zeros((table._cap, 48), np.uint8)
 
@@ -497,7 +503,7 @@ def _build_sequential_table_scalarmul(n: int,
     from .ops.tkernel_calls import scalar_mul_g1_t, to_affine_g1_t
 
     table = DevicePubkeyTable()
-    table._cap = max(DevicePubkeyTable.MIN_CAPACITY, next_pow2(n))
+    table._cap = DevicePubkeyTable.capacity_for(n)
     table._host_x = np.zeros((table._cap, 48), np.uint8)
     table._host_y = np.zeros((table._cap, 48), np.uint8)
 

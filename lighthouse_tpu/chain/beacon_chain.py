@@ -456,9 +456,15 @@ class BeaconChain:
         p = self.spec.preset
         head = self._head
         state = head.state
-        if int(state.slot) < slot:
-            state = complete_state_advance(state.copy(), None, slot, self.spec)
         epoch = slot // p.SLOTS_PER_EPOCH
+        # Advance only across an epoch boundary: within the head state's
+        # epoch, slot processing changes neither the checkpoints nor the
+        # shuffling, and a target at or after the head's slot is the head
+        # root — the same data without a state copy, which costs seconds
+        # at 1M validators (reference: attestation production reads the
+        # head state unless the request is for a later epoch).
+        if int(state.slot) // p.SLOTS_PER_EPOCH < epoch:
+            state = complete_state_advance(state.copy(), None, slot, self.spec)
         committee = self._committee_at(state, slot, committee_index, epoch)
 
         # Target = block root at the epoch-start slot. When attesting AT

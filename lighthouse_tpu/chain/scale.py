@@ -15,7 +15,7 @@ Key scale techniques:
     build; a committee's aggregate signature is (sum sk_i mod r)*H(m);
   * aggregate/selection/aggregator signatures via ``bulk_g2_mul`` — one
     hash per distinct message, scalar multiplications batched on the
-    device G2 kernel (host fallback off-TPU);
+    device G2 kernel in fixed-width calls (host muls off-TPU);
   * the aggregator search evaluates candidates' selection proofs until
     one passes is_aggregator, exactly the VC's duty check.
 """
@@ -31,7 +31,7 @@ from ..consensus import helpers as h
 from ..consensus.config import ChainSpec, compute_signing_root
 from ..consensus.genesis import scale_genesis_state
 from ..consensus.ssz import uint64
-from ..consensus.types import spec_types
+from ..consensus.types import AttestationData, spec_types
 from ..crypto.bls.api import AggregateSignature, Signature
 from ..crypto.bls.constants import R as CURVE_ORDER
 from ..crypto.bls.curve import g2_to_compressed
@@ -60,10 +60,15 @@ def slot_shape(n_validators: int, spec: ChainSpec) -> tuple[int, int]:
     return committees, size
 
 
+# Lanes per device G2 scalar-mul call: every call pads to this, so a
+# whole run compiles ONE program for it (a chip compile costs seconds).
+BULK_LANES = 64
+
+
 def bulk_g2_mul(point, scalars: list[int]):
     """[k]P for one G2 point and many scalars.
 
-    On TPU: one fused scalar-mul kernel call over all lanes
+    On TPU: fused scalar-mul kernel calls of BULK_LANES lanes each
     (ops/tkernel_calls.scalar_mul_g2_t). Off-TPU the kernel would run
     in minutes-slow interpret mode, so small batches fall back to host
     muls — identical results, oracle-tested."""
@@ -71,20 +76,26 @@ def bulk_g2_mul(point, scalars: list[int]):
 
     if jax.default_backend() != "tpu" or len(scalars) < 8:
         return [point.mul(s) for s in scalars]
+    out = []
+    for lo in range(0, len(scalars), BULK_LANES):
+        out += _bulk_g2_mul_lanes(point, scalars[lo:lo + BULK_LANES])
+    return out
 
+
+def _bulk_g2_mul_lanes(point, scalars: list[int]):
     import jax.numpy as jnp
 
     from ..ops import points as pts
     from ..ops.tkernel_calls import scalar_mul_g2_t, to_affine_g2_t
-    from ..ops import tkernel as tk
 
     n = len(scalars)
+    scalars = list(scalars) + [1] * (BULK_LANES - n)
     px, py, _ = pts.g2_to_dev([point])
     # transposed layout: [2, 48] coefficient planes broadcast over lanes
-    x = jnp.broadcast_to(jnp.asarray(px[0])[:, :, None], (2, 48, n))
-    y = jnp.broadcast_to(jnp.asarray(py[0])[:, :, None], (2, 48, n))
-    inf = jnp.zeros((1, n), jnp.int32)
-    bits = np.zeros((256, n), np.int32)
+    x = jnp.broadcast_to(jnp.asarray(px[0])[:, :, None], (2, 48, BULK_LANES))
+    y = jnp.broadcast_to(jnp.asarray(py[0])[:, :, None], (2, 48, BULK_LANES))
+    inf = jnp.zeros((1, BULK_LANES), jnp.int32)
+    bits = np.zeros((256, BULK_LANES), np.int32)
     for j, s in enumerate(scalars):
         for b in range(256):
             bits[b, j] = (s >> (255 - b)) & 1
@@ -93,8 +104,8 @@ def bulk_g2_mul(point, scalars: list[int]):
     return pts.g2_from_dev(
         np.moveaxis(np.asarray(ax), -1, 0),
         np.moveaxis(np.asarray(ay), -1, 0),
-        np.asarray(ainf)[0] != 0,
-    )
+        np.asarray(ainf),
+    )[:n]
 
 
 class ScaleChain:
@@ -176,11 +187,21 @@ class ScaleChain:
         )
         h_slot = hash_to_g2(slot_root)
 
+        # One attestation production per slot: it advances a copy of the
+        # head state to ``slot``, which at 1M validators takes seconds;
+        # once per committee it kept the chip's host busy for over 20
+        # minutes without finishing a slot (PR 21). Committees differ only
+        # in their index and come from the chain's shuffling cache (the
+        # committees it verifies against).
+        base = self.chain.produce_unaggregated_attestation(slot, 0).data
         out = []
         for ci in range(n_comm):
-            att = self.chain.produce_unaggregated_attestation(slot, ci)
-            committee = h.get_beacon_committee(state, slot, ci, spec)
-            data = att.data
+            committee = self.chain._committee_at(state, slot, ci, epoch)
+            data = AttestationData(
+                slot=slot, index=ci,
+                beacon_block_root=base.beacon_block_root,
+                source=base.source, target=base.target,
+            )
             att_root = compute_signing_root(data, att_domain)
             sk_sum = sum(int(i) + 1 for i in committee) % CURVE_ORDER
             agg_sig = AggregateSignature(hash_to_g2(att_root).mul(sk_sum))

@@ -10,7 +10,7 @@ per-dispatch resilience layer cannot see:
   ``LHTPU_FAULT_INJECT`` injector: at each scheduled epoch the spec is
   installed for that epoch only, giving deterministic warmup → chaos →
   recovery phases. ``kind`` accepts the injector's literal kinds plus
-  two aliases: ``transient`` (→ ``remote_compile``, the r05 incident)
+  two aliases: ``transient`` (→ ``aborted``, an ABORTED runtime status)
   and ``permanent`` (→ ``mosaic``, the r04 incident).
 * **leak sentinels** — each epoch samples RSS
   (``common/monitoring.read_rss_bytes``), the jit-cache entry estimate,
@@ -53,7 +53,7 @@ from .serve import ServeConfig, ServingLoop, VirtualClock, WallClock, \
 from .traffic import TrafficConfig, TrafficGenerator
 
 #: chaos-schedule kind aliases onto the injector's literal kinds
-KIND_ALIASES = {"transient": "remote_compile", "permanent": "mosaic"}
+KIND_ALIASES = {"transient": "aborted", "permanent": "mosaic"}
 
 #: per-epoch seed stride (any odd prime; keeps epoch streams distinct
 #: yet fully determined by the base seed)

@@ -287,7 +287,7 @@ def _reduce_t(acc, interpret: bool):
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not tk.on_tpu()
 
 
 def msm_g2(sx, sy, idx, valid):

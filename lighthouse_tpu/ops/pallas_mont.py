@@ -139,7 +139,7 @@ def mont_mul_pallas(a, b):
     shape = jnp.broadcast_shapes(a.shape, b.shape)
     a = jnp.broadcast_to(a, shape).reshape(-1, N_LIMBS)
     b = jnp.broadcast_to(b, shape).reshape(-1, N_LIMBS)
-    interpret = jax.default_backend() != "tpu"
+    interpret = not tk.on_tpu()
     return _mont_mul_flat(
         a, b, interpret=interpret, mxu_carry=tk._mxu_carry_enabled()
     ).reshape(shape)

@@ -641,11 +641,19 @@ _GROUP_LOWMEM = 2  # smaller windows where VMEM is tight (lowmem kernels)
 # (tests flip the platform before first use).
 
 
+def on_tpu() -> bool:
+    """The one platform gate for the kernels' TPU-only choices: Mosaic
+    instead of interpret mode, the MXU fold, the scoped-VMEM grant.
+    Read at trace time. A compile for a described (not attached) chip
+    steers all three by patching this one function."""
+    return jax.default_backend() == "tpu"
+
+
 def _mxu_fold_enabled() -> bool:
     choice = _knobs.knob("LHTPU_MXU_FOLD")
     if choice is not None:
         return choice == "1"
-    return jax.default_backend() == "tpu"
+    return on_tpu()
 
 
 def ladder_stack_enabled() -> bool:
@@ -675,7 +683,7 @@ def vmem_params():
     128 MB of physical VMEM; grant kernels 64 MB (LHTPU_VMEM_LIMIT_MB
     overrides) and let the scheduler keep using what it needs.
     """
-    if jax.default_backend() != "tpu":
+    if not on_tpu():
         return None
     from jax.experimental.pallas import tpu as pltpu
 

@@ -39,7 +39,7 @@ ORDER_NBITS = len(ORDER_BITS_NP)
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not tk.on_tpu()
 
 
 def _col(bits_np: np.ndarray) -> jnp.ndarray:
@@ -53,8 +53,15 @@ def _pad_lanes(a, t_pad: int):
     return jnp.pad(a, pad)
 
 
-def _tile_for(t: int, cap: int) -> int:
-    return min(cap, max(128, -(-t // 128) * 128))
+# Every kernel tiles its lanes in blocks of TILE. Mosaic's code size, and
+# its compile time faster still, grow with the block width while the work
+# per lane does not: wider blocks cost a cold chip run minutes of compile
+# (CHANGES.md, PR 21).
+TILE = 128
+
+
+def _whole_tiles(t: int) -> int:
+    return -(-t // TILE) * TILE
 
 
 def _specs(shapes, tile):
@@ -104,7 +111,7 @@ def _scalar_mul_t(x, y, inf, bits, *, g2: bool, interpret: bool):
     """[k]Q per lane. x/y: [(2,)48,T]; inf: [1,T] int32; bits [nbits,T].
     Returns Jacobian (X, Y, Z) stacked [3, (2,) 48, T]."""
     t = x.shape[-1]
-    tile = _tile_for(t, 512 if not g2 else 256)
+    tile = TILE
     t_pad = -(-t // tile) * tile
     x, y, inf, bits = (_pad_lanes(v, t_pad) for v in (x, y, inf, bits))
     coord = (2, N_LIMBS) if g2 else (N_LIMBS,)
@@ -161,7 +168,7 @@ def _subgroup_kernel(x_ref, y_ref, inf_ref, obits_ref, consts_ref, mont_ref, out
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _subgroup_check_g2(x, y, inf, interpret: bool):
     t = x.shape[-1]
-    tile = _tile_for(t, 256)
+    tile = TILE
     t_pad = -(-t // tile) * tile
     x, y, inf = (_pad_lanes(v, t_pad) for v in (x, y, inf))
     in_specs = _specs(
@@ -234,7 +241,7 @@ def _subgroup_fast_kernel(x_ref, y_ref, inf_ref, consts_ref, mont_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _subgroup_check_g2_fast(x, y, inf, interpret: bool):
     t = x.shape[-1]
-    tile = _tile_for(t, 256)
+    tile = TILE
     t_pad = -(-t // tile) * tile
     x, y, inf = (_pad_lanes(v, t_pad) for v in (x, y, inf))
     in_specs = _specs(
@@ -284,7 +291,7 @@ def _to_affine_kernel(g2: bool):
 @functools.partial(jax.jit, static_argnames=("g2", "interpret"))
 def _to_affine_t(P, *, g2: bool, interpret: bool):
     t = P[0].shape[-1]
-    tile = _tile_for(t, 256)
+    tile = TILE
     t_pad = -(-t // tile) * tile
     stacked = _pad_lanes(jnp.stack(P), t_pad)
     coord = (2, N_LIMBS) if g2 else (N_LIMBS,)
@@ -310,14 +317,25 @@ def _to_affine_t(P, *, g2: bool, interpret: bool):
     return out[0, ..., :t], out[1, ..., :t], inf[0, :t] != 0
 
 
+def _to_affine_tiled(P, g2: bool):
+    # Whole tiles in: lane counts within one tile count share one trace
+    # of the jitted kernel across programs (a zero Z lane is infinity).
+    t = P[0].shape[-1]
+    x, y, inf = _to_affine_t(
+        tuple(_pad_lanes(c, _whole_tiles(t)) for c in P), g2=g2,
+        interpret=_interpret(),
+    )
+    return x[..., :t], y[..., :t], inf[:t]
+
+
 def to_affine_g1_t(P):
     """Jacobian -> affine (x, y, inf-bool); infinity lanes zeroed
     (points.pt_to_affine semantics)."""
-    return _to_affine_t(P, g2=False, interpret=_interpret())
+    return _to_affine_tiled(P, g2=False)
 
 
 def to_affine_g2_t(P):
-    return _to_affine_t(P, g2=True, interpret=_interpret())
+    return _to_affine_tiled(P, g2=True)
 
 
 # ----------------------------------------------------------- miller loop
@@ -338,7 +356,7 @@ def _miller_kernel(xp_ref, yp_ref, pinf_ref, xq_ref, yq_ref, qinf_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _miller_t(xp, yp, pinf, xq, yq, qinf, interpret: bool):
     t = xp.shape[-1]
-    tile = _tile_for(t, 128)
+    tile = TILE
     t_pad = -(-t // tile) * tile
     xp, yp, pinf, xq, yq, qinf = (
         _pad_lanes(v, t_pad) for v in (xp, yp, pinf, xq, yq, qinf)
@@ -367,22 +385,34 @@ def _miller_t(xp, yp, pinf, xq, yq, qinf, interpret: bool):
 
 
 def miller_loop_kernel_t(p_aff, p_inf, q_aff, q_inf):
-    """Batched Miller loop as one kernel; masks are bool [T]."""
-    return _miller_t(
-        p_aff[0], p_aff[1], p_inf[None, :].astype(jnp.int32),
-        q_aff[0], q_aff[1], q_inf[None, :].astype(jnp.int32),
+    """Batched Miller loop as one kernel; masks are bool [T]. Lanes go
+    in as whole tiles (padding lanes at infinity give Fp12 one), so the
+    verify program's S+1 pairs and the triage program's S+G pairs share
+    one trace of the jitted kernel."""
+    t = p_inf.shape[-1]
+    t_pad = _whole_tiles(t)
+
+    def row(m):
+        return _pad_lanes(m[None, :].astype(jnp.int32), t_pad).at[
+            :, t:].set(1)
+
+    f = _miller_t(
+        _pad_lanes(p_aff[0], t_pad), _pad_lanes(p_aff[1], t_pad), row(p_inf),
+        _pad_lanes(q_aff[0], t_pad), _pad_lanes(q_aff[1], t_pad), row(q_inf),
         _interpret(),
     )
+    return f[..., :t]
 
 
 # ------------------------------------------------------- final exponentiation
 
 
-# The full HHT chain holds four Fp12 values live (~3 MB each at a
-# 128-lane tile) plus product temporaries — over the 16 MB VMEM budget
-# as one program. It is therefore split into a pipeline of small
-# kernels (easy part / x-power / combine variants), each with ≤3 live
-# Fp12 values, all in lowmem mode (fp2-level stacking only).
+# The HHT chain is a pipeline of small kernels (easy part / x-power /
+# combine variants), each with ≤3 live Fp12 values, all in lowmem mode
+# (fp2-level stacking only). Separate kernels compile in parallel (one
+# kernel holding the whole chain took over 3x the compile wall time on a
+# described v5e), and the five x-powers share ONE call site inside an
+# XLA loop, so the x-power kernel compiles once (CHANGES.md, PR 21).
 
 _F12_SHAPE = (2, 3, 2, N_LIMBS)
 
@@ -395,18 +425,17 @@ def _easy_exp_kernel(f_ref, pinv_ref, consts_ref, mont_ref, out_ref):
         out_ref[:] = tk.fp12_mul_t(tk.fp12_frobenius2_t(g), g)
 
 
-def _pow_kernel(xm1: bool):
-    def kernel(f_ref, consts_ref, mont_ref, out_ref):
-        with tk.bound_consts(consts_ref[:], mont=mont_ref[:], lowmem=True):
-            f = f_ref[:]
-            p = tp._cyc_pow_x_t(f)
-            if xm1:  # f^(x-1) = f^x * conj(f)
-                p = tk.fp12_mul_t(p, tk.fp12_conj_t(f))
-            out_ref[:] = p
-
-    return kernel
+def _pow_kernel(f_ref, xm1_ref, consts_ref, mont_ref, out_ref):
+    """f^x, or f^(x-1) = f^x * conj(f) on lanes whose xm1 row is set."""
+    with tk.bound_consts(consts_ref[:], mont=mont_ref[:], lowmem=True):
+        f = f_ref[:]
+        p = tp._cyc_pow_x_t(f)
+        out_ref[:] = jnp.where(
+            xm1_ref[0, :] != 0, tk.fp12_mul_t(p, tk.fp12_conj_t(f)), p
+        )
 
 
+@functools.cache
 def _comb_kernel(mode: str):
     def kernel(u_ref, v_ref, consts_ref, mont_ref, out_ref):
         with tk.bound_consts(consts_ref[:], mont=mont_ref[:], lowmem=True):
@@ -427,18 +456,28 @@ def _comb_kernel(mode: str):
     return kernel
 
 
-def _f12_call(kernel, operands, extra_specs, extras, t, interpret):
-    tile = _tile_for(t, 128)
+@functools.partial(jax.jit, static_argnames=("kernel", "pinv", "interpret"))
+def _f12_call(kernel, operands, rows=(), *, pinv: bool, interpret: bool):
+    """One Fp12 kernel over the lanes of ``operands`` (plus int32 [1, T]
+    lane ``rows``). Jitted on the kernel: a repeated call reuses one
+    trace instead of re-tracing the kernel body."""
+    t = operands[0].shape[-1]
+    tile = TILE
     t_pad = -(-t // tile) * tile
-    operands = [_pad_lanes(o, t_pad) for o in operands]
-    in_specs = _specs(
-        [(_F12_SHAPE, True)] * len(operands) + extra_specs, tile
-    )
+    operands = [_pad_lanes(o, t_pad) for o in (*operands, *rows)]
+    specs = ([(_F12_SHAPE, True)] * (len(operands) - len(rows))
+             + [((1,), True)] * len(rows))
+    extra_specs = [((tk.N_CONSTS, N_LIMBS, 1), False),
+                   ((tk.N_MONT_ROWS, N_LIMBS), False)]
+    extras = [jnp.asarray(tk.CONSTS_NP), jnp.asarray(tk.MONT_MATS_NP)]
+    if pinv:
+        extra_specs.insert(0, ((tk.PINV_NBITS, 1), False))
+        extras.insert(0, _col(tk.PINV_BITS_NP))
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((*_F12_SHAPE, t_pad), jnp.int32),
         grid=(t_pad // tile,),
-        in_specs=in_specs,
+        in_specs=_specs(specs + extra_specs, tile),
         out_specs=_specs([(_F12_SHAPE, True)], tile)[0],
         interpret=interpret,
         compiler_params=tk.vmem_params(),
@@ -448,28 +487,36 @@ def _f12_call(kernel, operands, extra_specs, extras, t, interpret):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _final_exp_t(f, interpret: bool):
-    t = f.shape[-1]
-    consts = [jnp.asarray(tk.CONSTS_NP), jnp.asarray(tk.MONT_MATS_NP)]
-    cs = [((tk.N_CONSTS, N_LIMBS, 1), False),
-         ((tk.N_MONT_ROWS, N_LIMBS), False)]
+    def call(kernel, *operands, rows=(), pinv=False):
+        return _f12_call(kernel, operands, rows, pinv=pinv,
+                         interpret=interpret)
 
-    def pow_(g, xm1):
-        return _f12_call(_pow_kernel(xm1), [g], cs, consts, t, interpret)
+    g = call(_easy_exp_kernel, f, pinv=True)
+    lanes = jnp.ones((1, f.shape[-1]), jnp.int32)
 
-    def comb(u, v, mode):
-        return _f12_call(_comb_kernel(mode), [u, v], cs, consts,
-                         t, interpret)
+    # Hard part (pairing.py final_exponentiation, HHT chain): step i
+    # takes p = x^x (x^(x-1) for i < 2), then
+    #   i = 0, 1: x <- p            (a = g^((x-1)^2) after i = 1)
+    #   i = 2:    x <- p * frob(a)  (b)
+    #   i = 3:    x <- p
+    #   i = 4:    x <- p * frob2(b) * conj(b)
+    def step(i, carry):
+        x, a, b = carry
+        p = call(_pow_kernel, x, rows=(lanes * (i < 2),))
+        x = jax.lax.switch(
+            jnp.where(i == 2, 1, jnp.where(i == 4, 2, 0)),
+            (lambda: p,
+             lambda: call(_comb_kernel("b"), p, a),
+             lambda: call(_comb_kernel("c"), p, b)),
+        )
+        return x, jnp.where(i == 1, p, a), jnp.where(i == 2, x, b)
 
-    g = _f12_call(
-        _easy_exp_kernel, [f],
-        [((tk.PINV_NBITS, 1), False)] + cs,
-        [_col(tk.PINV_BITS_NP)] + consts, t, interpret,
-    )
-    a = pow_(pow_(g, True), True)
-    b = comb(pow_(a, False), a, "b")
-    c = comb(pow_(pow_(b, False), False), b, "c")
-    return comb(c, g, "final")
+    c, _, _ = jax.lax.fori_loop(0, 5, step, (g, g, g))
+    return call(_comb_kernel("final"), c, g)
 
 
 def final_exp_kernel_t(f):
-    return _final_exp_t(f, _interpret())
+    # Whole tiles in, as for the Miller loop: the verify program's one
+    # lane and the triage program's G lanes share one trace.
+    t = f.shape[-1]
+    return _final_exp_t(_pad_lanes(f, _whole_tiles(t)), _interpret())[..., :t]

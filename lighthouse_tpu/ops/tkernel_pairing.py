@@ -308,25 +308,37 @@ def _miller_segments():
 X_ADD_RUNS, X_TAIL = _miller_segments()
 
 
+def _x_run_len(seg):
+    """Doubling-only steps before add ``seg`` (a traced loop index) — a
+    select chain over the static X_ADD_RUNS, since kernels may not
+    capture array constants."""
+    n = jnp.int32(0)
+    for i, run in enumerate(X_ADD_RUNS):
+        n = jnp.where(seg == i, jnp.int32(run), n)
+    return n
+
+
 def segmented_x_walk(dbl, dbl_add):
     """Drive a double-and-add over |x|'s STATIC bit layout: callbacks get
     (acc) for a doubling-only step and (acc) for a dbl+add step. The
     caller provides the initial acc (the leading bit's value). Used by
     miller_loop_t and the subgroup kernel so the segment bookkeeping
-    lives in exactly one place."""
+    lives in exactly one place.
+
+    The five segments run as ONE rolled loop (an inner doubling loop of
+    per-segment length, then the dbl+add step), so each callback is
+    traced once: the same operations in the same order as inlining the
+    segments, at about a third of the kernel code. Mosaic's compile time
+    grows faster than the code does — the inlined form took minutes per
+    kernel on a described v5e (CHANGES.md, PR 21)."""
 
     def walk(acc):
-        def run_dbls(a, n):
-            if n == 0:
-                return a
-            if n == 1:
-                return dbl(a)
-            return jax.lax.fori_loop(0, n, lambda _i, x: dbl(x), a)
+        def segment(seg, a):
+            a = jax.lax.fori_loop(0, _x_run_len(seg), lambda _i, x: dbl(x), a)
+            return dbl_add(a)
 
-        for run in X_ADD_RUNS:
-            acc2 = run_dbls(acc, run)
-            acc = dbl_add(acc2)
-        return run_dbls(acc, X_TAIL)
+        acc = jax.lax.fori_loop(0, len(X_ADD_RUNS), segment, acc)
+        return jax.lax.fori_loop(0, X_TAIL, lambda _i, x: dbl(x), acc)
 
     return walk
 
@@ -385,5 +397,5 @@ def _cyc_pow_x_t(f):
 
 
 # The full HHT final-exponentiation chain lives as a split-kernel
-# pipeline in tkernel_calls._final_exp_t (one monolithic kernel blows
-# the VMEM budget); _cyc_pow_x_t above is its x-power building block.
+# pipeline in tkernel_calls._final_exp_t; _cyc_pow_x_t above is its
+# x-power building block.

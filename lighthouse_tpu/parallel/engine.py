@@ -325,7 +325,7 @@ def _fold_probe(n_dev: int):
     import jax
     import jax.numpy as jnp
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if n_dev not in _FOLD_PROBES:
@@ -334,7 +334,7 @@ def _fold_probe(n_dev: int):
         mesh = make_mesh(n_dev, mp=1)
 
         @partial(shard_map, mesh=mesh, in_specs=(P("dp"),), out_specs=P(),
-                 check_rep=False)
+                 check_vma=False)
         def body(x):
             part = jnp.sum(x, axis=0, keepdims=True)       # per-chip partial
             parts = jax.lax.all_gather(part, "dp")          # [d, 1, 8]

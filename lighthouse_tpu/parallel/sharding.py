@@ -35,7 +35,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..ops import limb
 from ..ops.pairing import (
@@ -99,7 +99,7 @@ def build_sharded_verifier(mesh: Mesh):
             P("dp"),                                      # r_bits
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def body(pk_x, pk_y, pk_inf, sx, sy, sinf, mx, my, minf, r_bits):
         S_loc, K_loc = pk_inf.shape
@@ -226,7 +226,7 @@ def build_sharded_fused_verifier(mesh: Mesh, with_msm: bool = False):
         mesh=mesh,
         in_specs=base_specs + msm_specs,
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def body(pk_x, pk_y, pk_inf, sx, sy, sinf, mx, my, minf, r_bits,
              *msm):
@@ -271,7 +271,7 @@ def build_sharded_fused_smoke(mesh: Mesh):
 
     @partial(
         shard_map, mesh=flat, in_specs=(P("dp"),), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def body(one_bits):  # [1, 64] per chip: the scalar 1, MSB-first
         T = one_bits.shape[0]
@@ -341,7 +341,7 @@ def build_sharded_grouped_verifier(mesh: Mesh, n_groups: int):
             P("dp"),                    # r_bits
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def body(pk_x, pk_y, pk_inf, sx, sy, sinf, mx, my, minf, r_bits):
         ok = _verify_core_grouped(
@@ -387,7 +387,7 @@ def build_sharded_fused_grouped_verifier(mesh: Mesh, n_groups: int):
             P("dp"),                    # r_bits
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def body(pk_x, pk_y, pk_inf, sx, sy, sinf, mx, my, minf, r_bits):
         return _verify_core_fused_grouped(
@@ -418,7 +418,7 @@ def build_sharded_fused_grouped_indexed_verifier(mesh: Mesh, n_groups: int):
             P("dp"),                    # r_bits
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def body(tx, ty, idx, pk_inf, sx, sy, sinf, mx, my, minf, r_bits):
         px = tx[idx].astype(jnp.int32)
@@ -457,7 +457,7 @@ def build_sharded_fused_indexed_verifier(mesh: Mesh, with_msm: bool = False):
             P("dp"),                    # r_bits
         ) + msm_specs,
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def body(tx, ty, idx, pk_inf, sx, sy, sinf, mx, my, minf, r_bits,
              *msm):

@@ -163,7 +163,7 @@ class TestDedupFaultDegradation:
         clean = _rows(be._hash_message_bytes(msgs, 4, g2_infinity()))
         monkeypatch.setenv("LHTPU_RETRY_BASE_MS", "0")
         monkeypatch.setenv(
-            "LHTPU_FAULT_INJECT", "htc_dedup:remote_compile:1"
+            "LHTPU_FAULT_INJECT", "htc_dedup:aborted:1"
         )
         resilience.rearm_faults()
         blsrt.reset_input_caches()

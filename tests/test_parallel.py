@@ -299,10 +299,7 @@ def test_fused_collectives_match_host():
     from lighthouse_tpu.ops.tower import fp12_to_dev
     from lighthouse_tpu.parallel import make_mesh
 
-    try:
-        from jax.sharding import shard_map
-    except ImportError:  # older jax layout
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = 8
     mesh = make_mesh(n, mp=1)
@@ -312,7 +309,7 @@ def test_fused_collectives_match_host():
         return mesh_all_ok(lanes, "dp")[None]
 
     f = jax.jit(shard_map(all_ok_prog, mesh=mesh, in_specs=P("dp"),
-                          out_specs=P("dp"), check_rep=False))
+                          out_specs=P("dp"), check_vma=False))
     lanes = np.ones((n, 4), bool)
     assert bool(np.asarray(f(lanes)).all())
     lanes[5, 2] = False
@@ -332,7 +329,7 @@ def test_fused_collectives_match_host():
 
     g = jax.jit(shard_map(fold_prog, mesh=mesh,
                           in_specs=(P("dp"), P("dp"), P("dp")),
-                          out_specs=(P(), P(), P()), check_rep=False))
+                          out_specs=(P(), P(), P()), check_vma=False))
     ax, ay, ainf = g(px, py, pinf)
     ex, ey, einf = g2_to_dev([g2_generator().mul(sum(ks))])
     assert not bool(np.asarray(ainf)[0])
@@ -358,7 +355,7 @@ def test_fused_collectives_match_host():
         return folded[None], n_fin[None]
 
     h = jax.jit(shard_map(fp12_prog, mesh=mesh, in_specs=P("dp"),
-                          out_specs=(P(), P("dp")), check_rep=False))
+                          out_specs=(P(), P("dp")), check_vma=False))
     folded, n_fin = h(vals)
     expect = jax.jit(fp12_fold_scan, static_argnums=1)(
         jnp.asarray(vals), n

@@ -234,14 +234,14 @@ def test_pipelined_sharded_verdicts_under_fault(eight_host_devices,
     monkeypatch.setenv("LHTPU_PIPELINE_MIN_SETS", "4")
     monkeypatch.setenv("LHTPU_PIPELINE_CHUNK", "8")
     monkeypatch.setenv(
-        "LHTPU_FAULT_INJECT", "sharded_dispatch:remote_compile:1"
+        "LHTPU_FAULT_INJECT", "sharded_dispatch:aborted:1"
     )
 
     sets = _sets(16)
     assert bool(be.verify_signature_sets(sets))
     assert be.last_path == "sharded-classic+pipeline"
     rep = jb.dispatch_stage_report()
-    assert rep["retries"].get("dispatch:remote_compile", 0) >= 1
+    assert rep["retries"].get("dispatch:aborted", 0) >= 1
     assert rep["parallel"]["devices"] == 8
     assert rep["pipeline"]["chunks"] == 2
 

@@ -58,3 +58,17 @@ def test_slot_of_aggregates_through_processor(scale_chain):
     # replays are deduped, not re-verified
     res2 = sc.drive_slot(aggs)
     assert res2["aggregates_verified"] == len(aggs)  # unchanged
+
+
+def test_bulk_g2_mul_device_lanes_match_host():
+    """The device G2 scalar-mul path bulk_g2_mul takes on the chip
+    (interpret mode here): one fixed-width program, results equal to
+    host multiplication, padding lanes dropped."""
+    from lighthouse_tpu.chain.scale import BULK_LANES, _bulk_g2_mul_lanes
+    from lighthouse_tpu.crypto.bls.curve import g2_generator
+
+    G = g2_generator()
+    ks = [3, 5, 123456789, 2**200 + 7]
+    got = _bulk_g2_mul_lanes(G, ks)
+    assert len(got) == len(ks) < BULK_LANES
+    assert all(g == G.mul(k) for g, k in zip(got, ks))

@@ -470,7 +470,7 @@ def test_stream_chaos_digest_parity_jax(triage_env):
             chaos=chaos,
         ).run()
 
-    chaos_rep = run("0:dispatch:remote_compile:1")
+    chaos_rep = run("0:dispatch:aborted:1")
     resilience.reset()
     clean_rep = run("")
     for rep in (chaos_rep, clean_rep):

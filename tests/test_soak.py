@@ -105,7 +105,7 @@ def soak_results():
             replay=True, traffic=traffic, serve=serve,
         )
         chaos = [ChaosEvent(epoch=0, stage="dispatch",
-                            kind="remote_compile", count=1)]
+                            kind="aborted", count=1)]
         out["transient"] = (
             SoakRunner(cfg, chaos=chaos, emit=lines.append).run(),
             _rows(lines),
@@ -145,7 +145,7 @@ def test_parse_chaos_schedule_aliases_and_forgiveness(capsys):
     assert [
         (e.epoch, e.stage, e.kind, e.count) for e in sched
     ] == [
-        (2, "dispatch", "remote_compile", 3),   # transient alias
+        (2, "dispatch", "aborted", 3),   # transient alias
         (4, "device_sync", "mosaic", 1),        # permanent alias
         (5, "pack", "hang", 2),                 # literal kinds pass through
     ]
@@ -157,7 +157,7 @@ def test_parse_chaos_schedule_aliases_and_forgiveness(capsys):
 def test_chaos_spec_for_epoch_joins_same_epoch_events():
     sched = parse_chaos_schedule("1:dispatch:transient:2;1:pack:mosaic:1")
     assert chaos_spec_for_epoch(sched, 1) == \
-        "dispatch:remote_compile:2,pack:mosaic:1"
+        "dispatch:aborted:2,pack:mosaic:1"
     assert chaos_spec_for_epoch(sched, 0) == ""
 
 
@@ -191,7 +191,7 @@ def test_two_epoch_chaos_soak_digest_parity(soak_results):
     assert all(r["accounting_balanced"] for r in rows)
     assert not any(r["wedged"] for r in rows)
     assert rows[0]["phase"] == "chaos" and rows[0]["retries"] >= 1
-    assert rows[0]["chaos"] == "dispatch:remote_compile:1"
+    assert rows[0]["chaos"] == "dispatch:aborted:1"
     # a transient is absorbed in-stage: nothing degrades
     assert rows[0]["degraded_dispatches"] == 0
     assert res["degraded_time_fraction"] < 1.0

@@ -260,16 +260,16 @@ def test_triage_transient_fault_retried_in_stage(triage_env, monkeypatch):
     """A transient during the grouped dispatch is retried in place —
     verdicts unchanged, no fallback."""
     monkeypatch.setenv(
-        "LHTPU_FAULT_INJECT", "hash_to_curve:remote_compile:1"
+        "LHTPU_FAULT_INJECT", "hash_to_curve:aborted:1"
     )
     r0 = resilience.RETRIES_TOTAL.value(
-        stage="hash_to_curve", kind="remote_compile"
+        stage="hash_to_curve", kind="aborted"
     )
     be = jb.JaxBackend()
     got = be.verify_signature_sets_triaged(_mixed_sets(4, (2,)))
     assert got == [True, True, False, True]
     assert resilience.RETRIES_TOTAL.value(
-        stage="hash_to_curve", kind="remote_compile"
+        stage="hash_to_curve", kind="aborted"
     ) > r0
     assert jb.dispatch_stage_report()["triage"]["fallback"] is None
 

@@ -223,7 +223,7 @@ def test_chaos_parity_under_weather(weather_env):
     flood retries in place and the verdict + slasher digests stay
     bit-identical to the chaos-free replay."""
     traffic = _base_traffic(**AXES)
-    chaos_rep = _run_stream(traffic, chaos="0:dispatch:remote_compile:1")
+    chaos_rep = _run_stream(traffic, chaos="0:dispatch:aborted:1")
     resilience.reset()
     clean_rep = _run_stream(traffic)
     assert (chaos_rep["stream"]["verdict_digest"]

@@ -113,8 +113,8 @@ def test_pipeline_matches_single_shot(monkeypatch, seed):
 @pytest.mark.parametrize(
     "spec,expect",
     [
-        ("hash_to_curve:remote_compile:1", "retried"),
-        ("device_sync:remote_compile:1", "retried"),
+        ("hash_to_curve:aborted:1", "retried"),
+        ("device_sync:aborted:1", "retried"),
         ("dispatch:mosaic:1", "degraded"),
     ],
 )

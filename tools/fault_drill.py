@@ -5,7 +5,7 @@ fault via ``LHTPU_FAULT_INJECT=<stage>:<kind>:1`` and runs a warm
 ``verify_signature_sets`` batch through the resilient backend, then
 checks the contract of `common/resilience.py`:
 
-* a *transient* kind (``remote_compile`` — the literal r05 failure)
+* a *transient* kind (``aborted`` — an ABORTED runtime status)
   must be absorbed by an in-stage retry: verdict True, >=1 retry
   recorded, no degradation;
 * a *permanent* kind (``mosaic`` — the literal r04 failure) must trip
@@ -49,7 +49,7 @@ TRIAGE_STAGES = ("pack", "hash_to_curve", "htc_dedup", "htc_map",
 
 #: kind -> (classifier category, human label)
 KINDS = (
-    ("remote_compile", "transient"),
+    ("aborted", "transient"),
     ("mosaic", "permanent"),
 )
 
@@ -392,7 +392,7 @@ def run_drill_slot_load(kinds=KINDS, backend=None):
 #: (transient → retried in place on the mesh; permanent, including a
 #: simulated chip loss, → circuit-break down to single-chip).
 MULTICHIP_KINDS = (
-    ("remote_compile", "transient"),
+    ("aborted", "transient"),
     ("mosaic", "permanent"),
     ("chip_loss", "permanent"),
 )
@@ -596,7 +596,7 @@ def run_drill_soak():
     per-epoch seed shifts can never form an odd-sized batch that would
     need a fresh device program mid-soak).
 
-    * ``transient mid-soak``: one ``dispatch:remote_compile`` fault at
+    * ``transient mid-soak``: one ``dispatch:aborted`` fault at
       epoch 1 of 3 — the run must PASS, re-promote to the primary rung
       within the recovery budget, and its per-epoch verdict digests
       must match the chaos-free replay bit-for-bit.
@@ -640,9 +640,9 @@ def run_drill_soak():
         )
 
     cells = (
-        ("remote_compile", "transient",
+        ("aborted", "transient",
          [ChaosEvent(epoch=1, stage="dispatch",
-                     kind="remote_compile", count=1)], True),
+                     kind="aborted", count=1)], True),
         ("mosaic", "permanent",
          [ChaosEvent(epoch=e, stage="dispatch", kind="mosaic", count=1)
           for e in (1, 2)], False),
@@ -716,7 +716,7 @@ def run_drill_stream():
 
     Contract per cell (and the scheduler invariants the matrix pins):
 
-    * ``transient mid-stream`` (``dispatch:remote_compile``) — retried
+    * ``transient mid-stream`` (``dispatch:aborted``) — retried
       in place: zero mismatches, >=1 retry, no rung degradation;
     * ``permanent mid-stream`` (``dispatch:mosaic``) — degrades down
       the ladder (>=1 degraded dispatch) with every verdict still
@@ -782,7 +782,7 @@ def run_drill_stream():
     os.environ.pop("LHTPU_FAULT_INJECT", None)
 
     cells = (
-        ("remote_compile", "transient", "0:dispatch:remote_compile:1", {}),
+        ("aborted", "transient", "0:dispatch:aborted:1", {}),
         ("mosaic", "permanent", "0:dispatch:mosaic:1", {}),
         ("assert", "cache", "0:sched_cache:assert:1", {"cache": True}),
         ("preempted", "preempt", "", {}),
@@ -951,8 +951,8 @@ def run_drill_weather():
 
     flood = "*:slashing_flood:2.0"
     cells = (
-        ("remote_compile", "reorg_transient",
-         "0:dispatch:remote_compile:1", "*:reorg_storm:0.9"),
+        ("aborted", "reorg_transient",
+         "0:dispatch:aborted:1", "*:reorg_storm:0.9"),
         ("mosaic", "flood_permanent", "0:dispatch:mosaic:1", flood),
         ("assert", "slasher", "0:slasher:assert:1", flood),
     )
@@ -1036,12 +1036,9 @@ def main() -> int:
 
     import jax
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    from lighthouse_tpu.common.compile_cache import configure
+
+    configure()
 
     triage_stages = QUICK_STAGES if "--quick" in sys.argv else TRIAGE_STAGES
     n_multichip = len(MULTICHIP_KINDS) if len(jax.devices()) > 1 else 0

@@ -11,10 +11,10 @@ REPS=5 with block_until_ready. Inputs are generator-point lanes — timing
 is data-independent (constant-time chains).
 
 ``--fresh`` runs each requested row in its OWN subprocess: one cold
-python → jax → kernel lifecycle per row, so a number can never ride a
-stale device sync or a warm tunnel left by an earlier kernel (the
-stale-sync hazard documented in README). Default rows under --fresh are
-the ISSUE 10 hash-side trio (sswu_iso, cofactor, psi_subgroup) whose
+python → jax → kernel lifecycle per row, so a number can never ride
+device state left by an earlier kernel. The parent stays off JAX until
+every child is done (one process holds the chip at a time). Default
+rows under --fresh are the ISSUE 10 hash-side trio (sswu_iso, cofactor, psi_subgroup) whose
 MXU-ladder/resident wins must be confirmed per-kernel from cold."""
 
 from __future__ import annotations
@@ -24,37 +24,8 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_PLATFORMS", "")
-
-import jax
-import jax.numpy as jnp
-
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache_tpu"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
-
-from lighthouse_tpu.jax_backend import _rand_bits_array
-from lighthouse_tpu.ops import tkernel as tk
-from lighthouse_tpu.ops import tkernel_calls as tc
-from lighthouse_tpu.ops.points import G1_GEN_DEV, G2_GEN_DEV
 
 REPS = int(os.environ.get("KBENCH_REPS", "5"))
-
-
-def timeit(label, fn):
-    t0 = time.perf_counter()
-    out = fn()
-    jax.block_until_ready(out)
-    compile_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for _ in range(REPS):
-        jax.block_until_ready(fn())
-    dt = (time.perf_counter() - t0) / REPS * 1e3
-    print(f"{label:28s} {dt:9.1f} ms   (first call {compile_s:.1f}s)")
-    sys.stdout.flush()
 
 
 #: default rows for --fresh: the hash-side kernels whose ISSUE 10 wins
@@ -98,14 +69,48 @@ def run_fresh(S: int, names) -> int:
     return failed
 
 
-def main():
+def _fresh_main() -> int:
     argv = [a for a in sys.argv[1:] if a != "--fresh"]
-    fresh = len(argv) != len(sys.argv) - 1
     S = int(argv[0]) if argv else 2048
-    if fresh:
-        names = argv[1:] or list(FRESH_NAMES)
-        print(f"device=fresh-subprocess S={S} reps={REPS}")
-        sys.exit(1 if run_fresh(S, names) else 0)
+    names = argv[1:] or list(FRESH_NAMES)
+    print(f"device=fresh-subprocess S={S} reps={REPS}")
+    return 1 if run_fresh(S, names) else 0
+
+
+# The --fresh parent never imports JAX: a process that has touched it
+# holds the chip, and each child needs the chip in turn.
+if __name__ == "__main__" and "--fresh" in sys.argv:
+    sys.exit(_fresh_main())
+
+import jax
+import jax.numpy as jnp
+
+from lighthouse_tpu.common.compile_cache import configure
+
+configure()
+
+from lighthouse_tpu.jax_backend import _rand_bits_array
+from lighthouse_tpu.ops import tkernel as tk
+from lighthouse_tpu.ops import tkernel_calls as tc
+from lighthouse_tpu.ops.points import G1_GEN_DEV, G2_GEN_DEV
+
+
+def timeit(label, fn):
+    t0 = time.perf_counter()
+    out = fn()
+    jax.block_until_ready(out)
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for _ in range(REPS):
+        jax.block_until_ready(fn())
+    dt = (time.perf_counter() - t0) / REPS * 1e3
+    print(f"{label:28s} {dt:9.1f} ms   (first call {compile_s:.1f}s)")
+    sys.stdout.flush()
+
+
+def main():
+    argv = sys.argv[1:]
+    S = int(argv[0]) if argv else 2048
     names = argv[1:] or [
         "scalar_g1", "scalar_g2", "subgroup", "to_affine_g1",
         "to_affine_g2", "miller", "sswu", "cofactor", "final_exp",

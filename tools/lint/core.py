@@ -29,7 +29,8 @@ LINT_VERSION = "1.0.0"
 
 #: directories never walked in full-tree mode
 SKIP_DIRS = {
-    ".git", "__pycache__", ".pytest_cache", ".jax_cache_tpu",
+    ".git", "__pycache__", ".pytest_cache", ".jax_cache", "chiprun_out",
+    "chip_checkout",
     ".claude", "build", "dist", "node_modules",
 }
 

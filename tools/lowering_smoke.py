@@ -1,238 +1,170 @@
-"""TPU lowering-smoke gate (VERDICT r4 item 2).
+"""Mosaic compile rehearsal for a described v5e (no chip needed).
 
-Round 4 was lost to an env-default flip (LHTPU_KS_CARRY=1) that broke
-Mosaic lowering of every fused Pallas kernel — committed without ever
-compiling on TPU, invisible to the CPU-only fast tier. This gate makes
-that class of regression impossible to ship:
+Compiles the main-path Pallas kernels at their production width, and the
+whole fused verify program for a 4,096-set batch, with the TPU compiler
+for a described ``v5e:2x2`` chip: ``interpret=False``, the MXU fold and
+the 64 MB scoped-VMEM grant as on the chip. What Mosaic refuses here
+(VMEM, tiling alignment, an unsupported primitive) it would refuse on
+the chip, so this runs before any chip call that touches ops/:
 
-  python tools/lowering_smoke.py            # fast set, <60 s
-  python tools/lowering_smoke.py --full     # every production kernel (~10 min)
-  python tools/lowering_smoke.py --run      # + execute one fused verify on TPU
+  JAX_PLATFORMS=cpu python tools/lowering_smoke.py                # all
+  JAX_PLATFORMS=cpu python tools/lowering_smoke.py --kernels-only
+  JAX_PLATFORMS=cpu python tools/lowering_smoke.py --only miller,final_exp
+  JAX_PLATFORMS=cpu python tools/lowering_smoke.py --knobs lazy,mxu_carry
+  JAX_PLATFORMS=cpu python tools/lowering_smoke.py --smoke  # chip_smoke's programs
+  python tools/lowering_smoke.py --run     # on the chip: one tiny verify
 
-The trick: ``jax.export`` with ``platforms=['tpu']`` runs the FULL
-Pallas->Mosaic lowering pass (jax/_src/pallas/mosaic/lowering.py) on any
-host — no TPU needed. The exact NotImplementedError that zeroed
-BENCH_r04 reproduces in seconds on a 1-core CPU box. Each kernel is
-lowered under BOTH carry paths (LHTPU_KS_CARRY=0 and =1) so a default
-flip in either direction is covered.
-
-RULE (README "Lowering smoke" section): run the fast set before every
-commit that touches ops/ or jax_backend.py; run --full before flipping
-any kernel-affecting env default. The fast tier also runs the cheapest
-case as a pytest (tests/test_lowering_smoke.py).
+Every case prints one JSON line (lower/compile seconds, whether a
+``tpu_custom_call`` is in the program, memory analysis); the exit code
+is 1 if any case failed. A compile passing here is not a chip run.
+``--run`` executes a 4-set fused verify on the attached chip, in this
+one process and nothing else. The quick tier-1 subset is
+tests/test_chip_compile.py.
 """
 
 from __future__ import annotations
 
+import json
 import os
+import resource
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
+
+# Knob sets the fused program can be compiled under (--knobs). "default"
+# is what the chip runs. Mosaic refuses both PR-18 carry knobs as of
+# PR 21 (CHANGES.md): lazy reduce needs 65.78 MB of scoped VMEM in the
+# Miller kernel (grant 64 MB); the MXU carry emits an i1 -> i32 vector
+# register bitcast Mosaic cannot lower. They stay off.
+KNOB_SETS = {
+    "default": {},
+    "lazy": {"LHTPU_LAZY_REDUCE": "1"},
+    "mxu_carry": {"LHTPU_MXU_CARRY": "1"},
+}
+# The kernels of the main path, cheapest first.
+MAIN_KERNELS = ("scalar_mul_g1", "subgroup_g2_fast", "msm_accum",
+                "scalar_mul_g2", "final_exp", "map_to_g2_resident",
+                "miller")
 
 
-def _mk_cases(full: bool):
-    """(name, build_fn, args) per production kernel, tiny shapes (S=128:
-    one lane tile). Import inside so env mutation precedes jax import."""
-    import jax
-    import jax.numpy as jnp
-
-    from lighthouse_tpu.jax_backend import _rand_bits_array
-    from lighthouse_tpu.ops import tkernel_calls as tc
-    from lighthouse_tpu.ops.points import G1_GEN_DEV, G2_GEN_DEV
-
-    S = 128
-    g1x = jnp.broadcast_to(jnp.asarray(G1_GEN_DEV[0])[:, None], (48, S))
-    g1y = jnp.broadcast_to(jnp.asarray(G1_GEN_DEV[1])[:, None], (48, S))
-    g2x = jnp.broadcast_to(jnp.asarray(G2_GEN_DEV[0])[..., None], (2, 48, S))
-    g2y = jnp.broadcast_to(jnp.asarray(G2_GEN_DEV[1])[..., None], (2, 48, S))
-    inf_row = jnp.zeros((1, S), jnp.int32)
-    bits_t = jnp.transpose(jnp.asarray(_rand_bits_array(S)))
-
-    # Fast set: cheapest-to-trace kernels that still exercise every
-    # carry/mont-mul code path (add/sub/canonical/mont_mul ride inside
-    # the group law — Fp via the G1 ladder, Fp2 via the MSM mixed-add).
-    cases = [
-        ("scalar_mul_g1", lambda: tc.scalar_mul_g1_t(g1x, g1y, inf_row, bits_t)),
-    ]
-
-    def msm_accum():
-        from lighthouse_tpu.ops import msm as _msm
-
-        L = 8  # one grid step (schedule depth is padded to multiples of 8)
-        W = _msm._LANES
-        gx = jnp.broadcast_to(
-            jnp.asarray(G2_GEN_DEV[0])[None, ..., None], (L, 2, 48, W))
-        gy = jnp.broadcast_to(
-            jnp.asarray(G2_GEN_DEV[1])[None, ..., None], (L, 2, 48, W))
-        valid = jnp.ones((L, 1, W), jnp.int32)
-        return _msm._accum_t(gx, gy, valid, False)
-
-    cases.append(("msm_accum", msm_accum))
-
-    if full:
-        def sswu():
-            from lighthouse_tpu.ops.tkernel_htc import _sswu_iso_t
-
-            return _sswu_iso_t(g2x, False)
-
-        def cofactor():
-            from lighthouse_tpu.ops.tkernel_htc import _cofactor_t
-
-            jac2 = (g2x, g2y, jnp.broadcast_to(
-                jnp.concatenate(
-                    [jnp.asarray(tc.tk._c("R"))[None],
-                     jnp.zeros((1, 48, 1), jnp.int32)]
-                ),
-                (2, 48, S),
-            ))
-            return _cofactor_t(jac2, False)
-
-        def final_exp():
-            f = jnp.broadcast_to(
-                jnp.zeros((2, 3, 2, 48, 1), jnp.int32)
-                .at[0, 0, 0].set(tc.tk._c("R")),
-                (2, 3, 2, 48, S),
-            )
-            return tc.final_exp_kernel_t(f)
-
-        cases += [
-            ("scalar_mul_g2", lambda: tc.scalar_mul_g2_t(
-                g2x, g2y, inf_row, bits_t)),
-            ("subgroup_fast", lambda: tc.subgroup_check_g2_fast_t(
-                g2x, g2y, inf_row)),
-            ("to_affine_g1", lambda: tc.to_affine_g1_t(
-                (g1x, g1y, jnp.broadcast_to(tc.tk._c("R"), (48, S))))),
-            ("miller", lambda: tc.miller_loop_kernel_t(
-                (g1x, g1y), inf_row[0] != 0, (g2x, g2y), inf_row[0] != 0)),
-            ("sswu_iso", sswu),
-            ("cofactor", cofactor),
-            ("final_exp", final_exp),
-        ]
-    return cases
+def _arg(flag: str, default: str | None = None) -> str | None:
+    if flag in sys.argv:
+        return sys.argv[sys.argv.index(flag) + 1]
+    return default
 
 
-def _lower_all(full: bool, ks: str) -> list[str]:
-    """Export-lower every case for platform 'tpu' in THIS process with
-    LHTPU_KS_CARRY=ks. Returns failure strings."""
-    os.environ["LHTPU_KS_CARRY"] = ks
-    # Mosaic lowering needs no device; force-exercise the TPU kernel
-    # path (interpret mode off) regardless of host platform.
-    os.environ.setdefault("LHTPU_MXU_FOLD", "1")
+def _emit(row: dict) -> None:
+    print(json.dumps(row), flush=True)
 
-    import jax
 
-    fails = []
-    for name, fn in _mk_cases(full):
-        t0 = time.time()
+def rehearse() -> int:
+    from lighthouse_tpu.common.compile_cache import disable_persistent_cache
+
+    disable_persistent_cache()  # a described-chip entry cannot be read back
+
+    from lighthouse_tpu import jax_backend as jb
+    from lighthouse_tpu.ops import chip_compile as cc
+
+    _, one_chip = cc.describe_v5e()
+    only = _arg("--only")
+    names = only.split(",") if only else list(MAIN_KERNELS)
+    knob_names = (_arg("--knobs", "default") or "").split(",")
+    fails = 0
+
+    def run(label: str, knob_name: str, case) -> None:
+        nonlocal fails
+        fn, args, static = case
+        t0 = time.perf_counter()
+        row = {"case": label, "knobs": knob_name}
         try:
-            jax.export.export(jax.jit(fn), platforms=["tpu"])()
-            print(f"  ks={ks} {name:16s} lowered OK ({time.time() - t0:.0f}s)",
-                  flush=True)
-        except Exception as e:
-            print(f"  ks={ks} {name:16s} FAILED: {str(e)[:160]}", flush=True)
-            fails.append(f"ks={ks} {name}: {str(e)[:200]}")
-    return fails
+            row.update(cc.compile_case(fn, args, static, one_chip))
+            row["ok"] = bool(row["tpu_custom_call"])
+        except Exception as exc:  # lhtpu: ignore[LH502] -- a refusal is this tool's result: reported per case, counted in the exit code
+            row.update(ok=False, error=f"{type(exc).__name__}: {exc}"[:4000])
+        row["wall_s"] = round(time.perf_counter() - t0, 1)
+        row["max_rss_gb"] = round(
+            resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20, 2)
+        for k in ("lower_s", "compile_s"):
+            if k in row:
+                row[k] = round(row[k], 1)
+        fails += not row["ok"]
+        _emit(row)
+
+    if "--smoke" in sys.argv:
+        import chip_smoke
+        from lighthouse_tpu.blsrt import DevicePubkeyTable
+
+        rows = DevicePubkeyTable.capacity_for(chip_smoke.N_VALIDATORS)
+        with cc.steer_tpu():
+            programs = chip_smoke.warm_programs(int(chip_smoke.CHUNK), rows)
+            for name, cases in programs.items():
+                for case in cases:
+                    run(f"{name}:{case[0].__name__}", "default", case)
+        return 1 if fails else 0
+    if names != ["none"]:
+        with cc.steer_tpu():
+            cases = cc.kernel_cases()
+            for name in names:
+                run(name, "default", cases[name])
+    if "--kernels-only" not in sys.argv:
+        for knob_name in knob_names:
+            with cc.steer_tpu(KNOB_SETS[knob_name]):
+                run(f"fused_verify_S{cc.S_REAL}", knob_name,
+                    jb.fused_program(cc.S_REAL))
+    return 1 if fails else 0
 
 
-def _run_fused_verify() -> list[str]:
-    """Execute one tiny fused verify on the attached TPU (the final
-    word: lowering AND Mosaic compile AND numerics). Uses the
-    persistent cache; a code change invalidates it, which is the
-    point."""
+def run_on_chip() -> int:
+    """One 4-set fused verify on the attached chip, good and tampered."""
     import jax
 
-    if jax.default_backend() != "tpu":
-        return [f"--run requires a TPU backend (got {jax.default_backend()})"]
+    from lighthouse_tpu.common.compile_cache import configure
 
-    jax.config.update(
-        "jax_compilation_cache_dir", os.path.join(_REPO, ".jax_cache_tpu")
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
-
-    import jax.numpy as jnp
+    configure()
+    if jax.devices()[0].platform != "tpu":
+        print(f"--run needs a TPU (got {jax.devices()[0].platform})",
+              file=sys.stderr)
+        return 1
 
     from lighthouse_tpu.crypto.bls.api import SecretKey, SignatureSet
-    from lighthouse_tpu.crypto.bls.hash_to_curve import hash_to_g2
-    from lighthouse_tpu.jax_backend import (
-        _rand_scalars,
-        _verify_fused_jit,
-    )
-    from lighthouse_tpu.ops import msm as _msm
-    from lighthouse_tpu.ops.points import g1_to_dev, g2_to_dev
+    from lighthouse_tpu.jax_backend import JaxBackend
+
+    # 4 sets is under the small-batch native route: pin the device.
+    os.environ["LHTPU_HOST_FALLBACK"] = "0"
 
     S = 4
     sks = [SecretKey.from_int(i + 101) for i in range(S)]
     msgs = [i.to_bytes(32, "big") for i in range(S)]
-    sets = [
-        SignatureSet.single_pubkey(sk.sign(m), sk.public_key(), m)
-        for sk, m in zip(sks, msgs)
-    ]
-    px, py, pinf = g1_to_dev([s.signing_keys[0].point for s in sets])
-    px, py = px.reshape(S, 1, 48), py.reshape(S, 1, 48)
-    pinf = pinf.reshape(S, 1)
-    sx, sy, sinf = g2_to_dev([s.signature.point for s in sets])
-    mx, my, minf = g2_to_dev([hash_to_g2(m) for m in msgs])
-    r_u64, r_bits = _rand_scalars(S)
-    args = (
-        (jnp.asarray(px), jnp.asarray(py)), jnp.asarray(pinf),
-        (jnp.asarray(sx), jnp.asarray(sy)), jnp.asarray(sinf),
-        (jnp.asarray(mx), jnp.asarray(my)), jnp.asarray(minf),
-        jnp.asarray(r_bits),
-    )
-    sched = _msm.build_schedule(r_u64, _msm.max_rounds(S))
-    if sched is not None:
-        args = args + (jnp.asarray(sched[0]), jnp.asarray(sched[1]))
-    t0 = time.time()
-    ok = bool(_verify_fused_jit(*args))
-    print(f"  fused verify S={S} on TPU: {ok} ({time.time() - t0:.0f}s)",
-          flush=True)
-    return [] if ok else ["fused verify returned False on TPU"]
+    sets = [SignatureSet.single_pubkey(sk.sign(m), sk.public_key(), m)
+            for sk, m in zip(sks, msgs)]
+    bad = list(sets)
+    bad[0] = SignatureSet.single_pubkey(sets[1].signature,
+                                        sets[0].signing_keys[0], msgs[0])
+    backend = JaxBackend()
+    t0 = time.perf_counter()
+    good_v = backend.verify_signature_sets(sets)
+    bad_v = backend.verify_signature_sets(bad)
+    row = {"case": f"fused_verify_S{S}_on_chip", "good": good_v,
+           "tampered": bad_v, "path": backend.last_path,
+           "wall_s": round(time.perf_counter() - t0, 1)}
+    row["ok"] = good_v is True and bad_v is False
+    _emit(row)
+    return 0 if row["ok"] else 1
 
 
 def main() -> int:
-    full = "--full" in sys.argv
-    run = "--run" in sys.argv
-    t0 = time.time()
-    fails: list[str] = []
-
-    # Each KS mode lowers in a fresh subprocess: tkernel's traced
-    # programs cache per-process, and env flips after first trace are
-    # exactly the bug class this gate exists to catch.
-    import subprocess
-
-    for ks in ("0", "1"):
-        print(f"[lowering-smoke] export-lower for TPU, LHTPU_KS_CARRY={ks}",
-              flush=True)
-        r = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--child", ks] + (["--full"] if full else []),
-            env={**os.environ, "JAX_PLATFORMS": "cpu"},
-            cwd=_REPO,
-        )
-        if r.returncode != 0:
-            fails.append(f"ks={ks}: child rc={r.returncode}")
-
-    if run and not fails:
-        print("[lowering-smoke] executing fused verify on TPU", flush=True)
-        fails += _run_fused_verify()
-
-    dt = time.time() - t0
-    if fails:
-        print(f"[lowering-smoke] FAILED in {dt:.0f}s:", flush=True)
-        for f in fails:
-            print(f"  - {f}", flush=True)
-        return 1
-    print(f"[lowering-smoke] PASS in {dt:.0f}s "
-          f"({'full' if full else 'fast'} set, ks=0+1"
-          f"{', fused verify run' if run else ''})", flush=True)
-    return 0
+    if "--run" in sys.argv:
+        return run_on_chip()
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    t0 = time.perf_counter()
+    rc = rehearse()
+    print(f"[lowering-smoke] {'PASS' if rc == 0 else 'FAILED'} in "
+          f"{time.perf_counter() - t0:.0f}s", flush=True)
+    return rc
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
-        ks = sys.argv[sys.argv.index("--child") + 1]
-        sys.exit(1 if _lower_all("--full" in sys.argv, ks) else 0)
     sys.exit(main())

@@ -1,8 +1,10 @@
-"""Measure dryrun_multichip(8) cold wall time, emulating the driver host.
+"""Measure dryrun_multichip(8) cold wall time.
 
-Redirects the persistent compile cache to an empty temp dir so every XLA
-compile is cold (the committed .jax_cache doesn't AOT-load cross-machine —
-MULTICHIP_r03.json tail), then runs the gate exactly as the driver does.
+Points the persistent compile cache at an empty directory through
+JAX_COMPILATION_CACHE_DIR (which lighthouse_tpu/common/compile_cache.py
+honours) so every XLA compile is cold, then runs the gate:
+
+  python tools/measure_dryrun_cold.py [cache_dir]
 """
 
 import os
@@ -14,21 +16,10 @@ os.environ.setdefault(
     "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
 )
 os.environ["JAX_PLATFORMS"] = "cpu"
-
-cold = sys.argv[1] if len(sys.argv) > 1 else tempfile.mkdtemp(prefix="cold_jax_cache_")
-
-import jax  # noqa: E402
-
-_orig_update = jax.config.update
-
-
-def _patched(name, val):
-    if name == "jax_compilation_cache_dir":
-        val = cold
-    _orig_update(name, val)
-
-
-jax.config.update = _patched
+os.environ["JAX_COMPILATION_CACHE_DIR"] = (
+    sys.argv[1] if len(sys.argv) > 1
+    else tempfile.mkdtemp(prefix="cold_jax_cache_")
+)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import __graft_entry__  # noqa: E402

@@ -69,12 +69,9 @@ if DEVICES and DEVICES > 1:
 import jax
 import jax.numpy as jnp
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache_tpu"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+from lighthouse_tpu.common.compile_cache import configure
+
+configure()
 
 from lighthouse_tpu.crypto.bls.api import SecretKey, SignatureSet
 from lighthouse_tpu.crypto.bls.hash_to_curve import hash_to_g2
@@ -408,16 +405,6 @@ def profile_multichip(n_dev: int) -> None:
 
     out = sys.stderr if JSON_MODE else sys.stdout
     tpu = jax.devices()[0].platform == "tpu"
-    if not tpu:
-        # reuse the test tier's cache — the sharded classic programs at
-        # the (S=8, K=1) profile shape are exactly what it compiles
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), ".jax_cache"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
     S = int(os.environ.get("PROFILE_SETS", "2048" if tpu else "8"))
     print(f"device={jax.devices()[0].platform} devices={n_dev} S={S} "
           f"(multichip profile)", file=out)

@@ -78,14 +78,9 @@ def main() -> int:
     # Persistent compile cache (same store as the test suite): a soak
     # measures lifetime behavior, not compile latency — epoch 0 should
     # reload the fast-tier buckets instead of paying minutes of XLA:CPU.
-    import jax
+    from lighthouse_tpu.common.compile_cache import configure
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    configure()
 
     from lighthouse_tpu.common import resilience
     from lighthouse_tpu.loadgen.serve import ServeConfig
