@@ -1,0 +1,9 @@
+"""Share of the window in which no operation ran on the device: 1 minus
+the union of device-op intervals over the window, from the profiler
+trace (gossip cell)."""
+
+from harness import trace
+
+
+def read(facts):
+    return trace.idle_share_pct(facts.get("trace"))
