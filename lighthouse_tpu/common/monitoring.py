@@ -4,8 +4,10 @@ remote endpoint in the beaconcha.in client-stats format) plus the
 psutil-free process self-observation the health governor feeds on:
 an RSS reader (``/proc/self/status`` VmRSS with a
 ``resource.getrusage`` fallback) behind the
-``process_resident_memory_bytes`` gauge, and a jit-cache entry
-estimate behind ``bls_jit_cache_entries``."""
+``process_resident_memory_bytes`` gauge, the one compile counter of
+every jitted device program (``bls_jit_cache_events_total``, through
+:func:`jit_cache_probe`), and a jit-cache entry estimate behind
+``bls_jit_cache_entries``."""
 
 from __future__ import annotations
 
@@ -58,9 +60,43 @@ def sample_rss() -> int:
     return rss
 
 
+JIT_CACHE_EVENTS = REGISTRY.counter(
+    "bls_jit_cache_events_total",
+    "Jitted device-program dispatches by compile-cache outcome",
+    ("fn", "event"),
+)
+
+
+def jit_cache_probe(fn, label: str):
+    """Sample ``fn``'s jit cache size; returns a closure that, called
+    after ``calls`` dispatches of ``fn`` (default 1), records each in
+    ``bls_jit_cache_events_total{fn=label}`` as a hit or a miss (the
+    cache's growth is the number of fresh traces/compiles). Counts
+    nothing when the runtime doesn't expose _cache_size (non-jit
+    callables, older jax)."""
+    try:
+        before = fn._cache_size()
+    except Exception:  # lhtpu: ignore[LH502] -- _cache_size is a private jax API; absent means cache accounting is off, not an error
+        return lambda calls=1: None
+
+    def done(calls: int = 1) -> None:
+        try:
+            after = fn._cache_size()
+        except Exception:  # lhtpu: ignore[LH502] -- same probe after dispatch; losing one sample is fine
+            return
+        misses = min(max(after - before, 0), calls)
+        if misses:
+            JIT_CACHE_EVENTS.inc(misses, fn=label, event="miss")
+            note_jit_compile(after - before)
+        if calls > misses:
+            JIT_CACHE_EVENTS.inc(calls - misses, fn=label, event="hit")
+
+    return done
+
+
 # Jit-cache entry accounting: JAX exposes no stable global cache-size
-# API, so we count compiles (jax_backend's jit-cache probe calls
-# note_jit_compile on every miss) and re-baseline on a counted clear.
+# API, so we count compiles (jit_cache_probe calls note_jit_compile on
+# every miss) and re-baseline on a counted clear.
 _JIT_LOCK = threading.Lock()
 _JIT_COMPILES = 0
 _JIT_BASELINE = 0

@@ -7,15 +7,18 @@ against the global ``lighthouse_metrics`` registry scraped by
 is a timed context manager: spans nest through a thread-local stack,
 finished roots land in a bounded ring buffer, and every span's duration
 is mirrored into registry histograms — the shared ``lhtpu_span_seconds``
-family labelled by span name, plus an optional caller-supplied histogram
-with its own labels — so ONE instrumentation point feeds the Prometheus
+family labelled by span name (unless opened with ``mirror=False``),
+plus an optional caller-supplied histogram with its own labels — so
+ONE instrumentation point feeds the Prometheus
 scrape (``/metrics``), the Chrome-trace export (``/trace`` or
-``chrome_trace()``), and the bench's per-stage breakdown.
+``chrome_trace()``), and the profiler: an enabled span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so while a JAX profiler
+trace is recording, every span lands on the trace's host plane, on the
+clock the device operations use.
 
 Overhead discipline: with ``LHTPU_TRACE=0`` every ``span()`` call
-returns the shared no-op span — no clock read, no allocation, nothing
-on the measured path. Enabled is the default: one span costs ~1 µs
-against millisecond-scale dispatch stages.
+returns the shared no-op span — no clock read, no allocation, no JAX
+import, nothing on the measured path. Enabled is the default.
 
 Usage::
 
@@ -26,12 +29,10 @@ Usage::
         sp.set(padded=S)
 
     tracing.chrome_trace()   # -> chrome://tracing / Perfetto events
-    tracing.to_dicts()       # -> JSON-able nested span tree
 """
 
 from __future__ import annotations
 
-import json
 import os
 import threading
 import time
@@ -40,8 +41,9 @@ from collections import deque
 from . import knobs
 from .metrics import REGISTRY, Histogram
 
-#: finished ROOT spans kept for export (children ride their root)
-MAX_ROOT_SPANS = 256
+#: spans kept for export, children included: the oldest finished roots
+#: are dropped, whole, until their spans fit (the newest root is kept)
+MAX_SPANS = 16384
 
 _enabled = bool(knobs.knob("LHTPU_TRACE"))
 
@@ -85,28 +87,48 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+_annotation = None  # jax.profiler.TraceAnnotation, or False without JAX
+
+
+def _annotation_cls():
+    """The profiler's annotation type, imported on the first enabled
+    span; False where JAX cannot be imported (spans then only time)."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _annotation = TraceAnnotation
+    return _annotation
+
 
 class Span:
     """One timed region. Context manager; nests via the owning tracer's
     thread-local stack. ``metric``/``labels``: an extra Histogram to
-    mirror the duration into (on top of ``lhtpu_span_seconds``)."""
+    mirror the duration into (on top of ``lhtpu_span_seconds``, unless
+    ``mirror`` is false)."""
 
     __slots__ = (
-        "name", "attrs", "start", "end", "children", "tid",
-        "_tracer", "_metric", "_labels",
+        "name", "attrs", "start", "end", "children", "tid", "size",
+        "_tracer", "_metric", "_labels", "_mirror", "_ann",
     )
 
     def __init__(self, tracer: "Tracer", name: str,
-                 metric: Histogram | None, labels: dict | None, attrs: dict):
+                 metric: Histogram | None, labels: dict | None,
+                 mirror: bool, attrs: dict):
         self.name = name
         self.attrs = attrs
         self.start = 0.0
         self.end: float | None = None
         self.children: list[Span] = []
         self.tid = threading.get_ident()
+        self.size = 1  # this span and every span nested in it
         self._tracer = tracer
         self._metric = metric
-        self._labels = labels or {}
+        self._labels = labels
+        self._mirror = mirror
+        self._ann = None
 
     @property
     def duration(self) -> float | None:
@@ -117,11 +139,18 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._tracer._stack().append(self)
+        ann = _annotation_cls()
+        if ann:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
         self.start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if exc_type is not None:
             # failures stay attributed even when the caller re-raises
             self.attrs.setdefault("error", exc_type.__name__)
@@ -132,37 +161,28 @@ class Span:
             if stack:
                 stack.pop()
         dur = self.end - self.start
-        SPAN_SECONDS.observe(dur, span=self.name)
+        if self._mirror:
+            SPAN_SECONDS.observe(dur, span=self.name)
         if self._metric is not None:
-            self._metric.observe(dur, **self._labels)
+            self._metric.observe(dur, **(self._labels or {}))
         if stack:
             stack[-1].children.append(self)
+            stack[-1].size += self.size
         else:
             self._tracer._add_root(self)
         return False
 
-    # ------------------------------------------------------------- export
-    def to_dict(self) -> dict:
-        d = {
-            "name": self.name,
-            "start": self.start,
-            "duration": self.duration,
-            "tid": self.tid,
-        }
-        if self.attrs:
-            d["attrs"] = dict(self.attrs)
-        if self.children:
-            d["children"] = [c.to_dict() for c in self.children]
-        return d
-
 
 class Tracer:
-    """Thread-local span stacks + a bounded ring of finished roots."""
+    """Thread-local span stacks + a ring of finished roots bounded by
+    the number of spans they hold."""
 
-    def __init__(self, max_roots: int = MAX_ROOT_SPANS):
+    def __init__(self, max_spans: int = MAX_SPANS):
         self._local = threading.local()
         self._lock = threading.Lock()
-        self._roots: deque[Span] = deque(maxlen=max_roots)
+        self._roots: deque[Span] = deque()
+        self._spans = 0  # spans held by self._roots
+        self._max_spans = max_spans
         self._origin = time.perf_counter()
 
     def _stack(self) -> list:
@@ -174,14 +194,20 @@ class Tracer:
     def _add_root(self, span: Span) -> None:
         with self._lock:
             self._roots.append(span)
+            self._spans += span.size
+            while self._spans > self._max_spans and len(self._roots) > 1:
+                self._spans -= self._roots.popleft().size
 
     # ---------------------------------------------------------------- API
     def span(self, name: str, metric: Histogram | None = None,
-             labels: dict | None = None, **attrs):
-        """A new active span, or the shared no-op when tracing is off."""
+             labels: dict | None = None, mirror: bool = True, **attrs):
+        """A new active span, or the shared no-op when tracing is off.
+        ``mirror=False`` keeps the span out of ``lhtpu_span_seconds``:
+        for spans opened once per item of a hot loop, whose observe
+        would cost more than the rest of the span."""
         if not _enabled:
             return NULL_SPAN
-        return Span(self, name, metric, labels, attrs)
+        return Span(self, name, metric, labels, mirror, attrs)
 
     def current(self) -> Span | None:
         """The innermost open span on THIS thread (None outside spans)."""
@@ -195,12 +221,7 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._roots.clear()
-
-    def to_dicts(self) -> list[dict]:
-        return [r.to_dict() for r in self.roots()]
-
-    def to_json(self) -> str:
-        return json.dumps(self.to_dicts())
+            self._spans = 0
 
     def chrome_trace(self) -> list[dict]:
         """Finished spans as Chrome trace-event 'X' (complete) events —
@@ -231,9 +252,10 @@ TRACER = Tracer()
 
 
 def span(name: str, metric: Histogram | None = None,
-         labels: dict | None = None, **attrs):
+         labels: dict | None = None, mirror: bool = True, **attrs):
     """Module-level convenience for ``TRACER.span`` (the common call)."""
-    return TRACER.span(name, metric=metric, labels=labels, **attrs)
+    return TRACER.span(name, metric=metric, labels=labels, mirror=mirror,
+                       **attrs)
 
 
 def current_span() -> Span | None:
@@ -246,14 +268,6 @@ def roots() -> list[Span]:
 
 def clear() -> None:
     TRACER.clear()
-
-
-def to_dicts() -> list[dict]:
-    return TRACER.to_dicts()
-
-
-def to_json() -> str:
-    return TRACER.to_json()
 
 
 def chrome_trace() -> list[dict]:

@@ -105,11 +105,6 @@ DISPATCH_BATCH_KEYS = REGISTRY.histogram(
     "Total signing keys per dispatched batch (pre-padding)",
     buckets=_POW2_BUCKETS,
 )
-JIT_CACHE_EVENTS = REGISTRY.counter(
-    "bls_jit_cache_events_total",
-    "Verify-program jit dispatches by compile-cache outcome",
-    ("fn", "event"),
-)
 NATIVE_LOAD_FAILURES = REGISTRY.counter(
     "native_backend_load_failures_total",
     "Native C++ BLS backend load attempts that found no usable library",
@@ -207,29 +202,6 @@ def _retry_stage(name: str, stages: dict, fn):
     return resilience.call_with_retries(attempt, stage=name)
 
 
-def _jit_cache_probe(fn, label: str):
-    """Sample ``fn``'s jit cache size; returns a closure that, called
-    after the dispatch, records hit vs miss (a growth in cache size is
-    a fresh trace/compile). Counts nothing when the runtime doesn't
-    expose _cache_size (non-jit callables, older jax)."""
-    try:
-        before = fn._cache_size()
-    except Exception:  # lhtpu: ignore[LH502] -- _cache_size is a private jax API; absent means cache accounting is off, not an error
-        return lambda: None
-
-    def done():
-        try:
-            after = fn._cache_size()
-        except Exception:  # lhtpu: ignore[LH502] -- same probe after dispatch; losing one sample is fine
-            return
-        miss = after > before
-        JIT_CACHE_EVENTS.inc(fn=label, event="miss" if miss else "hit")
-        if miss:
-            monitoring.note_jit_compile(after - before)
-
-    return done
-
-
 def dispatch_stage_report() -> dict:
     """Stage attribution of the most recent _dispatch: per-stage wall
     times, cumulative per-stage error counts, and the stage the last
@@ -245,7 +217,7 @@ def dispatch_stage_report() -> dict:
         },
         "jit_cache": {
             f"{lbl['fn']}:{lbl['event']}": v
-            for lbl, v in JIT_CACHE_EVENTS.items()
+            for lbl, v in monitoring.JIT_CACHE_EVENTS.items()
         },
         "retries": {
             f"{lbl['stage']}:{lbl['kind']}": v
@@ -728,8 +700,7 @@ def _verify_core_fused_grouped(pk, pk_inf, sig, sig_inf, msg, msg_inf,
     f_grp = fp12_mul(f_grp, f_c[S:])
 
     # Final exponentiation: the kernel is already lane-batched, so the
-    # G group lanes ride one program (tools/profile_stages.py --json
-    # reports the G-lane vs 1-lane overhead for group-count tuning).
+    # G group lanes ride one program.
     fe = tc.final_exp_kernel_t(tk.batch_to_t(f_grp))
     ok = tower.fp12_is_one(tk.batch_from_t(fe)) & sub_ok  # [G]
     if axis is not None:
@@ -1745,7 +1716,7 @@ class JaxBackend:
                     )
                     label = ("sharded-indexed+triage" if fused
                              else "sharded-classic-indexed+triage")
-                    probe = _jit_cache_probe(fn, label)
+                    probe = monitoring.jit_cache_probe(fn, label)
                     ok = fn(pk.tx, pk.ty, idx, pinf, *tail)
                     self.last_path = label
                 else:
@@ -1753,7 +1724,7 @@ class JaxBackend:
                           else _verify_indexed_grouped_jit)
                     label = ("indexed+triage" if fused
                              else "classic-indexed+triage")
-                    probe = _jit_cache_probe(fn, label)
+                    probe = monitoring.jit_cache_probe(fn, label)
                     ok = fn(
                         pk.tx, pk.ty, idx, pinf,
                         (tail[0], tail[1]), tail[2],
@@ -1768,7 +1739,7 @@ class JaxBackend:
                 )
                 label = ("sharded+triage" if fused
                          else "sharded-classic+triage")
-                probe = _jit_cache_probe(fn, label)
+                probe = monitoring.jit_cache_probe(fn, label)
                 ok = fn(
                     jnp.asarray(pk.px), jnp.asarray(pk.py),
                     jnp.asarray(pk.pinf), *tail,
@@ -1778,7 +1749,7 @@ class JaxBackend:
                 fn = (_verify_fused_grouped_jit if fused
                       else _verify_grouped_jit)
                 label = "fused+triage" if fused else "classic+triage"
-                probe = _jit_cache_probe(fn, label)
+                probe = monitoring.jit_cache_probe(fn, label)
                 ok = fn(
                     (jnp.asarray(pk.px), jnp.asarray(pk.py)),
                     jnp.asarray(pk.pinf),
@@ -2195,7 +2166,7 @@ class JaxBackend:
                 )
                 label = ("sharded-indexed" if fused
                          else "sharded-classic-indexed")
-                probe = _jit_cache_probe(fn, label)
+                probe = monitoring.jit_cache_probe(fn, label)
                 ok = fn(
                     tx, ty, jnp.asarray(idx), jnp.asarray(tinf),
                     tail[0][0], tail[0][1], tail[1],
@@ -2210,7 +2181,7 @@ class JaxBackend:
                     n_dev, fused=fused, with_msm=bool(msm_args)
                 )
                 label = "sharded" if fused else "sharded-classic"
-                probe = _jit_cache_probe(fn, label)
+                probe = monitoring.jit_cache_probe(fn, label)
                 ok = fn(
                     jnp.asarray(px), jnp.asarray(py), jnp.asarray(pinf),
                     tail[0][0], tail[0][1], tail[1],
@@ -2222,13 +2193,13 @@ class JaxBackend:
                 fn = (_verify_fused_indexed_jit if fused
                       else _verify_indexed_jit)
                 label = "indexed" if fused else "classic-indexed"
-                probe = _jit_cache_probe(fn, label)
+                probe = monitoring.jit_cache_probe(fn, label)
                 ok = fn(tx, ty, jnp.asarray(idx), jnp.asarray(tinf), *tail,
                         *msm_args)
                 self.last_path = label
             else:
                 fn = _verify_fused_jit if fused else _verify_jit
-                probe = _jit_cache_probe(
+                probe = monitoring.jit_cache_probe(
                     fn, "fused" if fused else "classic"
                 )
                 ok = fn((jnp.asarray(px), jnp.asarray(py)),

@@ -17,7 +17,8 @@ from __future__ import annotations
 
 import zlib
 
-from ..common import knobs, resilience
+from ..common import knobs, monitoring, resilience, tracing
+from ..common.metrics import REGISTRY
 
 MAX_DISTANCE = 0xFFFF
 
@@ -27,6 +28,22 @@ MAX_DISTANCE = 0xFFFF
 CODE_SURROUNDS = 1
 CODE_SURROUNDED = 2
 CODE_DOUBLE = 4
+
+
+# SurroundEngine's counters, each advanced once per batch (reference:
+# the slasher's batch metrics in slasher/src/metrics.rs).
+VOTES_TOTAL = REGISTRY.counter(
+    "slasher_votes_total", "Votes classified by the surround engine")
+LAUNCHES_TOTAL = REGISTRY.counter(
+    "slasher_launches_total",
+    "Per-chunk device plane-scan programs launched by the surround engine")
+PLANE_INITS_TOTAL = REGISTRY.counter(
+    "slasher_plane_inits_total",
+    "Validator chunks whose device planes were made fresh")
+FALLBACKS_TOTAL = REGISTRY.counter(
+    "slasher_fallbacks_total",
+    "Chunks the surround engine replayed on the host after a fault",
+    ("kind",))
 
 
 def _col(name: str) -> bytes:
@@ -223,6 +240,7 @@ class SurroundEngine:
                 import jax.numpy as jnp
 
                 self._jax, self._jnp = jax, jnp
+                self._scan = self._build_scan()
                 self.device = True
             except Exception:
                 if forced == "1":
@@ -241,31 +259,53 @@ class SurroundEngine:
     def process(self, votes: list[tuple[int, int, int]]) -> list[int]:
         """Classify ``(validator, source, target)`` votes in order;
         returns one code-bit int per vote, aligned with the input."""
-        self.processed += len(votes)
-        groups: dict[int, list[tuple[int, int, int, int]]] = {}
-        for pos, (v, s, t) in enumerate(votes):
-            chunk, vi = divmod(int(v), self.validator_chunk_size)
-            groups.setdefault(chunk, []).append((pos, vi, int(s), int(t)))
-        codes = [0] * len(votes)
-        for chunk in sorted(groups):
-            items = groups[chunk]
-            try:
-                resilience.maybe_inject("slasher")
-                if self.device and not self.degraded:
-                    out = self._process_device(chunk, items)
-                else:
-                    out = self._process_host(chunk, items)
-            except Exception as exc:
-                _, kind = resilience.classify(exc)
-                self.fault_kinds[kind] = self.fault_kinds.get(kind, 0) + 1
-                self.fallbacks += 1
-                self.degraded = True
-                out = self._process_host(chunk, items, rebuild=True)
-            for (pos, _, _, _), code in zip(items, out):
-                codes[pos] = code
-            self._log.setdefault(chunk, []).extend(
-                (vi, s, t) for _, vi, s, t in items
-            )
+        with tracing.span("slasher/batch", votes=len(votes)) as batch:
+            self.processed += len(votes)
+            with tracing.span("slasher/group"):
+                groups: dict[int, list[tuple[int, int, int, int]]] = {}
+                for pos, (v, s, t) in enumerate(votes):
+                    chunk, vi = divmod(int(v), self.validator_chunk_size)
+                    groups.setdefault(chunk, []).append(
+                        (pos, vi, int(s), int(t)))
+            batch.set(chunks=len(groups))
+            codes = [0] * len(votes)
+            launches = slots = inits = 0
+            faults: dict[str, int] = {}
+            probe = monitoring.jit_cache_probe(self._scan, "slasher_scan")
+            for chunk in sorted(groups):
+                items = groups[chunk]
+                try:
+                    resilience.maybe_inject("slasher")
+                    if self.device and not self.degraded:
+                        out, pad, fresh = self._process_device(chunk, items)
+                        launches += 1
+                        slots += pad
+                        inits += fresh
+                    else:
+                        with tracing.span("slasher/host_path", mirror=False):
+                            out = self._process_host(chunk, items)
+                except Exception as exc:
+                    _, kind = resilience.classify(exc)
+                    self.fault_kinds[kind] = self.fault_kinds.get(kind, 0) + 1
+                    faults[kind] = faults.get(kind, 0) + 1
+                    self.fallbacks += 1
+                    self.degraded = True
+                    with tracing.span("slasher/host_path", mirror=False):
+                        out = self._process_host(chunk, items, rebuild=True)
+                for (pos, _, _, _), code in zip(items, out):
+                    codes[pos] = code
+                self._log.setdefault(chunk, []).extend(
+                    (vi, s, t) for _, vi, s, t in items
+                )
+            batch.set(slots=slots)
+            VOTES_TOTAL.inc(len(votes))
+            if launches:
+                probe(launches)
+                LAUNCHES_TOTAL.inc(launches)
+            if inits:
+                PLANE_INITS_TOTAL.inc(inits)
+            for kind, n in faults.items():
+                FALLBACKS_TOTAL.inc(n, kind=kind)
         return codes
 
     def report(self) -> dict:
@@ -340,22 +380,29 @@ class SurroundEngine:
 
         return jax.jit(run)
 
-    def _process_device(self, chunk: int, items) -> list[int]:
+    def _process_device(self, chunk: int,
+                        items) -> tuple[list[int], int, bool]:
+        """One chunk's votes through one plane-scan program: (codes,
+        vote slots scanned, whether the chunk's planes were made here)."""
         jnp = self._jnp
-        if self._scan is None:
-            self._scan = self._build_scan()
         n = len(items)
         pad = max(self.pad_floor, 1 << max(0, (n - 1).bit_length()))
-        rows = [(vi, s, t, 1) for _, vi, s, t in items]
-        rows += [(0, 0, 0, 0)] * (pad - n)
-        votes = jnp.asarray(rows, dtype=jnp.int32)
+        with tracing.span("slasher/prepare", mirror=False):
+            rows = [(vi, s, t, 1) for _, vi, s, t in items]
+            rows += [(0, 0, 0, 0)] * (pad - n)
+            votes = jnp.asarray(rows, dtype=jnp.int32)
         planes = self._dev.get(chunk)
-        if planes is None:
-            planes = self._fresh_device()
-        new_planes, codes = self._scan(planes, votes)
-        out = [int(c) for c in self._jax.device_get(codes)[:n]]
+        fresh = planes is None
+        if fresh:
+            with tracing.span("slasher/init_planes", mirror=False):
+                planes = self._fresh_device()
+        with tracing.span("slasher/launch", mirror=False):
+            new_planes, codes = self._scan(planes, votes)
+        with tracing.span("slasher/wait", mirror=False):
+            codes = self._jax.device_get(codes)
+        out = [int(c) for c in codes[:n]]
         self._dev[chunk] = new_planes
-        return out
+        return out, pad, fresh
 
     # ------------------------------------------------------------ host path
     def _fresh_host(self):

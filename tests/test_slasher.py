@@ -321,3 +321,179 @@ class TestDeviceSlasher:
         assert rep["fallbacks"] >= 1
         assert rep["degraded"] is True
         assert rep["fault_kinds"]
+
+
+def _slot_votes(epoch, validators, extra=()):
+    """One vote (epoch - 1 -> epoch) per validator, then ``extra``."""
+    return [(v, epoch - 1, epoch) for v in validators] + list(extra)
+
+
+def _engine_counts():
+    from lighthouse_tpu.common import monitoring
+    from lighthouse_tpu.slasher import arrays
+
+    return {
+        "votes": arrays.VOTES_TOTAL.value(),
+        "launches": arrays.LAUNCHES_TOTAL.value(),
+        "inits": arrays.PLANE_INITS_TOTAL.value(),
+        "miss": monitoring.JIT_CACHE_EVENTS.value(fn="slasher_scan",
+                                                  event="miss"),
+        "hit": monitoring.JIT_CACHE_EVENTS.value(fn="slasher_scan",
+                                                 event="hit"),
+    }
+
+
+def _delta(before, after):
+    return {k: after[k] - before[k] for k in before}
+
+
+class TestEngineTracing:
+    """SurroundEngine's spans and counters, on a tiny engine (chunk 8,
+    history 64): four validator chunks of 8."""
+
+    @pytest.fixture
+    def engine(self, monkeypatch):
+        pytest.importorskip("jax")
+        from lighthouse_tpu.slasher.arrays import SurroundEngine
+
+        monkeypatch.setenv("LHTPU_SLASHER_DEVICE", "1")
+        return SurroundEngine(validator_chunk_size=8, history_length=64)
+
+    @pytest.fixture
+    def tracing_on(self):
+        from lighthouse_tpu.common import tracing
+
+        prev = tracing.set_enabled(True)
+        tracing.clear()
+        try:
+            yield tracing
+        finally:
+            tracing.set_enabled(prev)
+            tracing.clear()
+
+    def test_one_batch_root_with_a_launch_and_wait_per_chunk(
+            self, engine, tracing_on):
+        # chunk 0 holds 9 votes (pad 16), chunks 1-3 hold 8 (pad 8)
+        first = _slot_votes(10, range(32), [(0, 8, 12)])
+        engine.process(first)
+        engine.process(_slot_votes(11, range(8, 24)))
+        roots = [r for r in tracing_on.roots()
+                 if r.name.startswith("slasher/")]
+        assert [r.name for r in roots] == ["slasher/batch"] * 2
+        assert roots[0].attrs == {"votes": 33, "chunks": 4, "slots": 40}
+        assert roots[1].attrs == {"votes": 16, "chunks": 2, "slots": 16}
+        for root, fresh in zip(roots, (True, False)):
+            kids = [c.name for c in root.children]
+            assert kids == ["slasher/group"] + (
+                ["slasher/prepare"] + ["slasher/init_planes"] * fresh
+                + ["slasher/launch", "slasher/wait"]) * root.attrs["chunks"]
+            assert root.size == 1 + sum(c.size for c in root.children)
+
+    def test_only_the_batch_spans_feed_the_span_histogram(
+            self, engine, tracing_on):
+        # per-chunk spans stay out of lhtpu_span_seconds: its observe
+        # would be most of their cost
+        def counts():
+            return {key[0]: shard.count for key, shard in
+                    tracing_on.SPAN_SECONDS._shards.items()
+                    if key[0].startswith("slasher/")}
+
+        before = counts()
+        engine.process(_slot_votes(10, range(32)))
+        after = counts()
+        grew = {k for k in after if after[k] != before.get(k, 0)}
+        assert grew == {"slasher/batch", "slasher/group"}
+        (root,) = [r for r in tracing_on.roots() if r.name == "slasher/batch"]
+        assert len(root.children) == 1 + 4 * 4
+
+    def test_counters_advance_once_per_batch_with_tracing_off(self, engine):
+        from lighthouse_tpu.common import tracing
+
+        prev = tracing.set_enabled(False)
+        try:
+            before = _engine_counts()
+            engine.process(_slot_votes(10, range(32), [(0, 8, 12)]))
+            got = _delta(before, _engine_counts())
+            assert {k: got[k] for k in ("votes", "launches", "inits")} == {
+                "votes": 33, "launches": 4, "inits": 4}
+            before = _engine_counts()
+            engine.process(_slot_votes(11, range(8, 24)))
+            got = _delta(before, _engine_counts())
+            assert {k: got[k] for k in ("votes", "launches", "inits")} == {
+                "votes": 16, "launches": 2, "inits": 0}
+        finally:
+            tracing.set_enabled(prev)
+
+    def test_a_compile_is_counted_on_a_new_pad_only(self, engine):
+        before = _engine_counts()
+        engine.process(_slot_votes(10, range(32)))       # pad 8: compiles
+        got = _delta(before, _engine_counts())
+        assert (got["miss"], got["hit"]) == (1, 3)
+        engine.process(_slot_votes(11, range(32)))       # pad 8 again
+        got = _delta(before, _engine_counts())
+        assert (got["miss"], got["hit"]) == (1, 7)
+        engine.process(_slot_votes(12, range(32), [(0, 11, 13)]))  # pad 16
+        got = _delta(before, _engine_counts())
+        assert (got["miss"], got["hit"]) == (2, 10)
+
+    def test_codes_do_not_depend_on_tracing(self, monkeypatch):
+        pytest.importorskip("jax")
+        from lighthouse_tpu.common import tracing
+        from lighthouse_tpu.slasher.arrays import SurroundEngine
+
+        batches = [
+            _slot_votes(10, range(32)),
+            # a double vote, a surrounding and a surrounded one
+            _slot_votes(11, range(8, 24), [(9, 10, 11), (3, 8, 12)]),
+            _slot_votes(14, range(4), [(12, 12, 13), (20, 9, 15)]),
+        ]
+        codes = {}
+        for device, on in (("1", False), ("1", True), ("0", False)):
+            monkeypatch.setenv("LHTPU_SLASHER_DEVICE", device)
+            prev = tracing.set_enabled(on)
+            try:
+                eng = SurroundEngine(validator_chunk_size=8,
+                                     history_length=64)
+                codes[device, on] = [eng.process(b) for b in batches]
+            finally:
+                tracing.set_enabled(prev)
+        assert codes["1", True] == codes["1", False] == codes["0", False]
+        assert any(any(c) for c in codes["1", True])
+
+    def test_disabled_tracing_leaves_no_roots(self, engine):
+        from lighthouse_tpu.common import tracing
+
+        prev = tracing.set_enabled(False)
+        tracing.clear()
+        try:
+            engine.process(_slot_votes(10, range(32)))
+            assert tracing.roots() == []
+        finally:
+            tracing.set_enabled(prev)
+
+    def test_fault_is_counted_and_traced_as_host_path(self, engine,
+                                                      tracing_on,
+                                                      monkeypatch):
+        from lighthouse_tpu.common import resilience
+        from lighthouse_tpu.slasher import arrays
+
+        before = arrays.FALLBACKS_TOTAL.items()
+        monkeypatch.setenv("LHTPU_FAULT_INJECT", "slasher:assert:1")
+        resilience.rearm_faults()
+        try:
+            engine.process(_slot_votes(10, range(32)))
+        finally:
+            monkeypatch.delenv("LHTPU_FAULT_INJECT")
+            resilience.rearm_faults()
+        kinds = engine.report()["fault_kinds"]
+        assert sum(kinds.values()) == 1
+        after = dict((lbl["kind"], v)
+                     for lbl, v in arrays.FALLBACKS_TOTAL.items())
+        was = dict((lbl["kind"], v) for lbl, v in before)
+        (kind,) = kinds
+        assert after[kind] - was.get(kind, 0) == 1
+        (root,) = [r for r in tracing_on.roots() if r.name == "slasher/batch"]
+        steps = [c.name for c in root.children[1:]]
+        # the faulted chunk and the three after it run on the host
+        assert steps.count("slasher/host_path") == 4
+        assert "slasher/launch" not in steps

@@ -1,9 +1,13 @@
 """Tracing-layer tests: span nesting/export round-trip, histogram
-mirroring, the LHTPU_TRACE=0 no-op contract, and Prometheus exposition
-of the new dispatch-stage metric families through api/http_metrics."""
+mirroring, the LHTPU_TRACE=0 no-op contract, spans as profiler
+annotations, and Prometheus exposition of the new dispatch-stage metric
+families through api/http_metrics."""
 
+import glob
 import json
+import os
 import threading
+import types
 import urllib.request
 
 from lighthouse_tpu.api.http_metrics import MetricsServer
@@ -28,13 +32,21 @@ class TestSpans:
         assert roots[0].duration >= sum(
             c.duration for c in roots[0].children
         )
-        # JSON export round-trips the structure and attrs
-        parsed = json.loads(tracer.to_json())
-        assert parsed[0]["name"] == "root"
-        assert parsed[0]["attrs"] == {"kind": "test"}
-        kids = parsed[0]["children"]
-        assert [k["name"] for k in kids] == ["child_a", "child_b"]
-        assert kids[1]["attrs"] == {"lanes": 4}
+        # the Chrome-trace export carries the structure and attrs
+        events = {e["name"]: e for e in tracer.chrome_trace()}
+        assert list(events) == ["root", "child_a", "grandchild", "child_b"]
+        assert events["root"]["args"] == {"kind": "test"}
+        assert events["child_b"]["args"] == {"lanes": 4}
+
+        def inside(inner, outer):
+            end = lambda e: e["ts"] + e["dur"]  # noqa: E731
+            return (outer["ts"] <= inner["ts"]
+                    and end(inner) <= end(outer) + 1e-6)
+
+        assert inside(events["child_a"], events["root"])
+        assert inside(events["grandchild"], events["child_a"])
+        assert inside(events["child_b"], events["root"])
+        assert not inside(events["child_b"], events["child_a"])
 
     def test_chrome_trace_events(self):
         tracer = tracing.Tracer()
@@ -62,13 +74,34 @@ class TestSpans:
         assert root.duration is not None
 
     def test_ring_buffer_bounded(self):
-        tracer = tracing.Tracer(max_roots=4)
+        tracer = tracing.Tracer(max_spans=4)
         for i in range(10):
             with tracer.span(f"s{i}"):
                 pass
         assert [r.name for r in tracer.roots()] == [
             "s6", "s7", "s8", "s9"
         ]
+
+    def test_ring_bounded_by_spans_not_roots(self):
+        tracer = tracing.Tracer(max_spans=5)
+
+        def root(name, children):
+            with tracer.span(name):
+                for i in range(children):
+                    with tracer.span(f"{name}/{i}"):
+                        pass
+
+        root("a", 2)
+        root("b", 1)
+        assert [r.size for r in tracer.roots()] == [3, 2]
+        root("c", 0)  # 6 spans: the oldest root goes, whole
+        assert [r.name for r in tracer.roots()] == ["b", "c"]
+        root("d", 7)  # larger than the ring: kept alone, as the newest
+        assert [r.name for r in tracer.roots()] == ["d"]
+        assert len(tracer.chrome_trace()) == 8
+        tracer.clear()
+        root("e", 3)
+        assert [r.name for r in tracer.roots()] == ["e"]
 
     def test_thread_isolation(self):
         tracer = tracing.Tracer()
@@ -103,18 +136,77 @@ class TestSpans:
             "lhtpu_span_seconds", "", ("span",)
         )
 
-    def test_disabled_is_noop(self):
+    def test_unmirrored_span_is_kept_but_not_observed(self):
+        def count(name):
+            shard = tracing.SPAN_SECONDS._shards.get((name,))
+            return shard.count if shard else 0
+
+        reg = Registry()
+        h = reg.histogram("step_seconds", "S", ("step",))
+        tracer = tracing.Tracer()
+        before = count("op/step"), count("op/outer")
+        with tracer.span("op/outer"):
+            with tracer.span("op/step", metric=h, labels={"step": "a"},
+                             mirror=False, rows=3) as sp:
+                pass
+        assert (count("op/step"), count("op/outer")) == (
+            before[0], before[1] + 1)
+        assert 'step_seconds_count{step="a"} 1' in reg.gather()
+        (root,) = tracer.roots()
+        assert root.children == [sp] and sp.attrs == {"rows": 3}
+
+    def test_disabled_is_noop(self, monkeypatch):
+        def no_clock():
+            raise AssertionError("a disabled span read the clock")
+
+        tracer = tracing.Tracer()
+        monkeypatch.setattr(tracing, "time",
+                            types.SimpleNamespace(perf_counter=no_clock))
+        monkeypatch.setattr(tracing, "_annotation", None)
         prev = tracing.set_enabled(False)
         try:
-            tracer = tracing.Tracer()
             sp = tracer.span("invisible", metric=None, attr=1)
             assert sp is tracing.NULL_SPAN
+            assert tracing.span("invisible") is tracing.NULL_SPAN
             with sp:
                 sp.set(anything="goes")
             assert tracer.roots() == []
             assert tracer.chrome_trace() == []
+            # no profiler annotation was looked up: JAX was not imported
+            assert tracing._annotation is None
         finally:
             tracing.set_enabled(prev)
+
+    def test_spans_are_profiler_annotations(self, tmp_path):
+        """An enabled span is written into a JAX profiler trace under
+        its name (CPU: the host plane), also when its body raises; a
+        disabled one is not."""
+        import jax
+        import jax.numpy as jnp
+
+        prev = tracing.set_enabled(True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracing.span("lhtpu_test/annotated"):
+                jnp.ones(8).sum().block_until_ready()
+            try:
+                with tracing.span("lhtpu_test/raised"):
+                    raise ValueError("inside")
+            except ValueError:
+                pass
+            tracing.set_enabled(False)
+            with tracing.span("lhtpu_test/disabled"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+            tracing.set_enabled(prev)
+        (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                            recursive=True)
+        names = {e.name
+                 for plane in jax.profiler.ProfileData.from_file(path).planes
+                 for line in plane.lines for e in line.events}
+        assert {"lhtpu_test/annotated", "lhtpu_test/raised"} <= names
+        assert "lhtpu_test/disabled" not in names
 
     def test_module_level_convenience(self):
         tracing.clear()
