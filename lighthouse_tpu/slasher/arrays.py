@@ -15,7 +15,11 @@ distances the same way).
 
 from __future__ import annotations
 
+import functools
+import itertools
 import zlib
+
+import numpy as np
 
 from ..common import knobs, monitoring, resilience, tracing
 from ..common.metrics import REGISTRY
@@ -36,13 +40,16 @@ VOTES_TOTAL = REGISTRY.counter(
     "slasher_votes_total", "Votes classified by the surround engine")
 LAUNCHES_TOTAL = REGISTRY.counter(
     "slasher_launches_total",
-    "Per-chunk device plane-scan programs launched by the surround engine")
+    "Device plane programs launched by the surround engine, one a batch")
+TILES_TOTAL = REGISTRY.counter(
+    "slasher_tiles_total",
+    "Vote tiles the surround engine's plane programs stepped through")
 PLANE_INITS_TOTAL = REGISTRY.counter(
     "slasher_plane_inits_total",
     "Validator chunks whose device planes were made fresh")
 FALLBACKS_TOTAL = REGISTRY.counter(
     "slasher_fallbacks_total",
-    "Chunks the surround engine replayed on the host after a fault",
+    "Batches the surround engine answered on the host after a fault",
     ("kind",))
 
 
@@ -196,13 +203,28 @@ class SurroundEngine:
     """Batched surround/double-vote detection on device (ISSUE 17).
 
     The per-vote state TargetArrays keeps in compressed KV chunks lives
-    here as resident ``[validator_chunk, history]`` int32 planes — min
-    distances (default MAX_DISTANCE), max distances (default 0), plus a
-    ring-occupancy plane for double-vote candidates. A ``jax.lax.scan``
-    walks the batch sequentially (votes for one validator must observe
-    each other, exactly as the host path does) while each vote's plane
-    update is vectorized across the full epoch ring — banded min/max
-    array work, the MXU/VPU fit the issue names.
+    here as three resident device planes of ``slots × chunk`` rows of
+    ``history`` epochs — min distances (int32, default MAX_DISTANCE), max
+    distances (int32, default 0) and ring occupancy for double-vote
+    candidates (bool), each row held as ``[history / 128, 128]``. A
+    validator chunk gets a slot the first time it receives a vote
+    (``_slot``, a host table), so the planes grow with the chunks
+    touched, and validator ``v`` owns row ``slot[v // chunk] × chunk +
+    v % chunk`` of each plane.
+
+    One ``process`` call is one device program: one upload, one launch,
+    one read-back. Only the order of one validator's votes matters (a
+    vote reads and writes its own row alone), so a vote's *round* is
+    the number of earlier votes of its validator in the batch, and the
+    votes go round after round in tiles of ``T_eff`` rows, no row twice
+    in a tile. A ``fori_loop`` walks the tiles: each gathers its rows of
+    the planes, computes the codes and the banded updates vectorized
+    across the epoch ring, and scatters the rows back. The planes are
+    donated, so the update is in place. ``T_eff`` is ``tile`` or, for
+    smaller batches, the largest round rounded up to a power of two (at
+    least ``pad_floor``); the tiles uploaded are a power of two, so a
+    batch compiles nothing new unless its (tiles, ``T_eff``) bucket or
+    the plane size is new.
 
     Verdict codes are bits (CODE_SURROUNDS / CODE_SURROUNDED /
     CODE_DOUBLE); the double bit is only a candidate — the caller
@@ -212,13 +234,15 @@ class SurroundEngine:
 
     Degradation: any fault inside ``process`` (including an injected
     ``slasher``-stage fault) trips a sticky host fallback. The engine
-    keeps a per-chunk vote log, so the fallback replays the chunk's
-    history into host mirror planes and continues — no findings lost,
-    same codes, no crash.
+    keeps a log of every batch's votes, so the fallback replays each
+    touched chunk's history into host mirror planes and answers the
+    whole batch there — no findings lost, same codes, no crash. The
+    donated device planes are never read again.
     """
 
     def __init__(self, validator_chunk_size: int | None = None,
-                 history_length: int | None = None, pad_floor: int = 8):
+                 history_length: int | None = None, pad_floor: int = 8,
+                 tile: int = 512):
         self.validator_chunk_size = (
             validator_chunk_size if validator_chunk_size is not None
             else int(knobs.knob("LHTPU_SLASHER_CHUNK"))
@@ -228,10 +252,11 @@ class SurroundEngine:
             else int(knobs.knob("LHTPU_SLASHER_HISTORY"))
         )
         self.pad_floor = max(1, pad_floor)
+        self.tile = max(self.pad_floor, tile)
         forced = knobs.knob("LHTPU_SLASHER_DEVICE")
         self._jax = None
         self._jnp = None
-        self._scan = None
+        self._program = None
         if forced == "0":
             self.device = False
         else:
@@ -240,7 +265,7 @@ class SurroundEngine:
                 import jax.numpy as jnp
 
                 self._jax, self._jnp = jax, jnp
-                self._scan = self._build_scan()
+                self._program = _plane_program()
                 self.device = True
             except Exception:
                 if forced == "1":
@@ -250,62 +275,58 @@ class SurroundEngine:
         self.fallbacks = 0
         self.fault_kinds: dict[str, int] = {}
         self.processed = 0
-        # per validator-chunk state
-        self._dev: dict[int, tuple] = {}          # chunk -> jnp planes
+        self._slot = np.full(0, -1, np.int64)     # chunk -> slot, -1 none
+        self._slots_used = 0                      # slots handed out
+        self._planes = None                       # (min, max, occ) planes
         self._host: dict[int, tuple] = {}         # chunk -> host mirror
-        self._log: dict[int, list] = {}           # chunk -> [(vi,s,t)]
+        self._log: list[np.ndarray] = []          # each batch's votes
 
     # ----------------------------------------------------------- public api
     def process(self, votes: list[tuple[int, int, int]]) -> list[int]:
         """Classify ``(validator, source, target)`` votes in order;
         returns one code-bit int per vote, aligned with the input."""
         with tracing.span("slasher/batch", votes=len(votes)) as batch:
+            if not votes:
+                return []
             self.processed += len(votes)
             with tracing.span("slasher/group"):
-                groups: dict[int, list[tuple[int, int, int, int]]] = {}
-                for pos, (v, s, t) in enumerate(votes):
-                    chunk, vi = divmod(int(v), self.validator_chunk_size)
-                    groups.setdefault(chunk, []).append(
-                        (pos, vi, int(s), int(t)))
-            batch.set(chunks=len(groups))
-            codes = [0] * len(votes)
-            launches = slots = inits = 0
-            faults: dict[str, int] = {}
-            probe = monitoring.jit_cache_probe(self._scan, "slasher_scan")
-            for chunk in sorted(groups):
-                items = groups[chunk]
-                try:
-                    resilience.maybe_inject("slasher")
-                    if self.device and not self.degraded:
-                        out, pad, fresh = self._process_device(chunk, items)
-                        launches += 1
-                        slots += pad
-                        inits += fresh
-                    else:
-                        with tracing.span("slasher/host_path", mirror=False):
-                            out = self._process_host(chunk, items)
-                except Exception as exc:
-                    _, kind = resilience.classify(exc)
-                    self.fault_kinds[kind] = self.fault_kinds.get(kind, 0) + 1
-                    faults[kind] = faults.get(kind, 0) + 1
-                    self.fallbacks += 1
-                    self.degraded = True
+                arr = np.fromiter(itertools.chain.from_iterable(votes),
+                                  np.int64, 3 * len(votes)).reshape(-1, 3)
+                chunk, vi = np.divmod(arr[:, 0], self.validator_chunk_size)
+                slot = self._assign_slots(chunk)
+                rows = slot * self.validator_chunk_size + vi
+                dest, t_eff, tiles, rounds = _tiles(rows, self.tile,
+                                                    self.pad_floor)
+            batch.set(chunks=int(np.count_nonzero(np.bincount(slot))),
+                      rounds=rounds)
+            launched = False
+            fault = None
+            try:
+                resilience.maybe_inject("slasher")
+                if self.device and not self.degraded:
+                    codes = self._process_device(arr, rows, dest, t_eff,
+                                                 tiles)
+                    launched = True
+                    batch.set(tiles=tiles, slots=tiles * t_eff)
+                else:
                     with tracing.span("slasher/host_path", mirror=False):
-                        out = self._process_host(chunk, items, rebuild=True)
-                for (pos, _, _, _), code in zip(items, out):
-                    codes[pos] = code
-                self._log.setdefault(chunk, []).extend(
-                    (vi, s, t) for _, vi, s, t in items
-                )
-            batch.set(slots=slots)
+                        codes = self._process_host(arr)
+            except Exception as exc:
+                _, fault = resilience.classify(exc)
+                self.fault_kinds[fault] = self.fault_kinds.get(fault, 0) + 1
+                self.fallbacks += 1
+                self.degraded = True
+                self._planes = None
+                self._host.clear()
+                with tracing.span("slasher/host_path", mirror=False):
+                    codes = self._process_host(arr)
+            self._log.append(arr)
             VOTES_TOTAL.inc(len(votes))
-            if launches:
-                probe(launches)
-                LAUNCHES_TOTAL.inc(launches)
-            if inits:
-                PLANE_INITS_TOTAL.inc(inits)
-            for kind, n in faults.items():
-                FALLBACKS_TOTAL.inc(n, kind=kind)
+            if launched:
+                LAUNCHES_TOTAL.inc()
+                TILES_TOTAL.inc(tiles)
+            if fault is not None:
+                FALLBACKS_TOTAL.inc(kind=fault)
         return codes
 
     def report(self) -> dict:
@@ -315,109 +336,99 @@ class SurroundEngine:
             "fallbacks": self.fallbacks,
             "fault_kinds": dict(self.fault_kinds),
             "votes": self.processed,
-            "chunks": len(self._log),
+            "chunks": self._slots_used,
         }
 
+    def _assign_slots(self, chunk: np.ndarray) -> np.ndarray:
+        """Each vote's chunk slot; chunks seen for the first time get
+        the next slots, in chunk order."""
+        if chunk.min() < 0:
+            raise ValueError("negative validator index")
+        top = int(chunk.max()) + 1
+        if top > len(self._slot):
+            self._slot = np.concatenate(
+                (self._slot, np.full(top - len(self._slot), -1, np.int64)))
+        slot = self._slot[chunk]
+        if (slot < 0).any():
+            new = np.unique(chunk[slot < 0])
+            first, self._slots_used = (self._slots_used,
+                                       self._slots_used + len(new))
+            self._slot[new] = np.arange(first, self._slots_used)
+            slot = self._slot[chunk]
+        return slot
+
     # ---------------------------------------------------------- device path
-    def _fresh_device(self):
+    def _grow_planes(self) -> int:
+        """Give every slot its plane rows; returns the chunks made
+        fresh. One plane at a time, each old buffer released before the
+        next plane grows, so the peak is one plane's old and new copy."""
         jnp = self._jnp
-        shape = (self.validator_chunk_size, self.history_length)
-        return (
-            jnp.full(shape, MAX_DISTANCE, dtype=jnp.int32),
-            jnp.zeros(shape, dtype=jnp.int32),
-            jnp.zeros(shape, dtype=jnp.bool_),
-        )
+        have = 0 if self._planes is None else (
+            self._planes[0].shape[0] // self.validator_chunk_size)
+        if have == self._slots_used:
+            return 0
+        with tracing.span("slasher/init_planes", mirror=False):
+            planes, self._planes = list(self._planes or ()), None
+            H = self.history_length
+            lanes = 128 if H % 128 == 0 else H
+            shape = ((self._slots_used - have) * self.validator_chunk_size,
+                     H // lanes, lanes)
+            for k, (fill, dtype) in enumerate(
+                    ((MAX_DISTANCE, jnp.int32), (0, jnp.int32),
+                     (False, jnp.bool_))):
+                if k < len(planes):
+                    old = planes[k]
+                    planes[k] = jnp.concatenate(
+                        (old, jnp.full(shape, fill, dtype=dtype)))
+                    old.delete()
+                else:
+                    planes.append(jnp.full(shape, fill, dtype=dtype))
+            self._planes = tuple(planes)
+        return self._slots_used - have
 
-    def _build_scan(self):
-        jax, jnp = self._jax, self._jnp
-        H = self.history_length
-
-        def step(carry, vote):
-            minp, maxp, occ = carry
-            vi, s, t, valid = vote[0], vote[1], vote[2], vote[3]
-            ok = valid != 0
-            # surround checks — same plane reads as
-            # TargetArrays.check_surround, surrounded takes priority
-            e1 = s - 1
-            d1 = maxp[vi, e1 % H]
-            surrounded = (s >= 1) & (d1 != 0) & (e1 + d1 > t)
-            e2 = s + 1
-            d2 = minp[vi, e2 % H]
-            surrounds = (d2 != MAX_DISTANCE) & (e2 + d2 < t)
-            dbl = occ[vi, t % H]
-            code = (
-                surrounds.astype(jnp.int32) * CODE_SURROUNDS
-                + surrounded.astype(jnp.int32) * CODE_SURROUNDED
-                + dbl.astype(jnp.int32) * CODE_DOUBLE
-            )
-            code = jnp.where(ok, code, 0)
-            # vectorized apply over every ring position p: recover the
-            # epoch e covering p inside the vote's affected band
-            p = jnp.arange(H, dtype=jnp.int32)
-            hi = jnp.minimum(t, s + H - 1)
-            off_max = (p - s) % H
-            e_max = s + off_max
-            in_max = off_max <= (hi - s)
-            d_max = jnp.minimum(t - e_max, MAX_DISTANCE - 1)
-            row_max = maxp[vi]
-            maxp = maxp.at[vi].set(
-                jnp.where(ok & in_max & (d_max > row_max), d_max, row_max)
-            )
-            lo = jnp.maximum(0, s - H + 1)
-            off_min = (s - p) % H
-            e_min = s - off_min
-            in_min = off_min <= (s - lo)
-            d_min = jnp.minimum(t - e_min, MAX_DISTANCE - 1)
-            row_min = minp[vi]
-            minp = minp.at[vi].set(
-                jnp.where(ok & in_min & (d_min < row_min), d_min, row_min)
-            )
-            occ = occ.at[vi, t % H].set(occ[vi, t % H] | ok)
-            return (minp, maxp, occ), code
-
-        def run(planes, votes):
-            return jax.lax.scan(step, planes, votes)
-
-        return jax.jit(run)
-
-    def _process_device(self, chunk: int,
-                        items) -> tuple[list[int], int, bool]:
-        """One chunk's votes through one plane-scan program: (codes,
-        vote slots scanned, whether the chunk's planes were made here)."""
-        jnp = self._jnp
-        n = len(items)
-        pad = max(self.pad_floor, 1 << max(0, (n - 1).bit_length()))
+    def _process_device(self, arr, rows, dest, t_eff: int,
+                        tiles: int) -> list[int]:
+        """The batch through one plane program; codes in input order."""
         with tracing.span("slasher/prepare", mirror=False):
-            rows = [(vi, s, t, 1) for _, vi, s, t in items]
-            rows += [(0, 0, 0, 0)] * (pad - n)
-            votes = jnp.asarray(rows, dtype=jnp.int32)
-        planes = self._dev.get(chunk)
-        fresh = planes is None
-        if fresh:
-            with tracing.span("slasher/init_planes", mirror=False):
-                planes = self._fresh_device()
+            buf = np.zeros((_pow2(tiles) * t_eff, 3), np.int32)
+            buf[:, 0] = -1
+            buf[dest, 0] = rows
+            buf[dest, 1:] = arr[:, 1:]
+            votes = self._jax.device_put(buf.reshape(-1, t_eff, 3))
+        fresh = self._grow_planes()
+        probe = monitoring.jit_cache_probe(self._program, "slasher_scan")
         with tracing.span("slasher/launch", mirror=False):
-            new_planes, codes = self._scan(planes, votes)
+            *planes, codes = self._program(*self._planes, votes,
+                                           np.int32(tiles))
+        self._planes = tuple(planes)
+        probe()
+        if fresh:
+            PLANE_INITS_TOTAL.inc(fresh)
         with tracing.span("slasher/wait", mirror=False):
             codes = self._jax.device_get(codes)
-        out = [int(c) for c in codes[:n]]
-        self._dev[chunk] = new_planes
-        return out, pad, fresh
+        return codes.reshape(-1)[dest].tolist()
 
     # ------------------------------------------------------------ host path
     def _fresh_host(self):
         n = self.validator_chunk_size * self.history_length
         return ([MAX_DISTANCE] * n, [0] * n, set())
 
-    def _process_host(self, chunk: int, items,
-                      rebuild: bool = False) -> list[int]:
-        planes = self._host.get(chunk)
-        if planes is None or rebuild:
-            planes = self._fresh_host()
-            self._host[chunk] = planes
-            for vi, s, t in self._log.get(chunk, ()):
-                self._host_vote(planes, vi, s, t)
-        return [self._host_vote(planes, vi, s, t) for _, vi, s, t in items]
+    def _process_host(self, arr: np.ndarray) -> list[int]:
+        """The batch on host mirror planes; a chunk's mirror is rebuilt
+        from the vote log the first time the host path meets it."""
+        C = self.validator_chunk_size
+        chunks = np.unique(arr[:, 0] // C)
+        need = chunks[[int(c) not in self._host for c in chunks]]
+        if len(need):
+            for c in need.tolist():
+                self._host[c] = self._fresh_host()
+            if self._log:
+                past = np.concatenate(self._log)
+                past = past[np.isin(past[:, 0] // C, need)]
+                for v, s, t in past.tolist():
+                    self._host_vote(self._host[v // C], v % C, s, t)
+        return [self._host_vote(self._host[v // C], v % C, s, t)
+                for v, s, t in arr.tolist()]
 
     def _host_vote(self, planes, vi: int, s: int, t: int) -> int:
         minp, maxp, occ = planes
@@ -449,3 +460,112 @@ class SurroundEngine:
                 minp[idx] = dd
         occ.add((vi, t % H))
         return code
+
+
+@functools.cache
+def _plane_program():
+    """The jitted program that steps one batch's tiles over the donated
+    planes, shared by every engine (the history is the planes' width)."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(minp, maxp, occ, votes, tiles):
+        # a plane row is [groups, lanes]: on the TPU a row is then whole
+        # (8, 128) tiles, one contiguous run of HBM, not one sublane of
+        # every tile of a [rows, history] plane
+        rows_total, G, L = minp.shape
+        H = G * L
+        _, T, _ = votes.shape
+        lane = jnp.arange(T, dtype=jnp.int32)
+        p = jnp.arange(H, dtype=jnp.int32).reshape(1, G, L)
+
+        def at(rows, pos):
+            """Each gathered row's value at ring position ``pos``."""
+            return rows[lane, pos // L, pos % L]
+
+        def tile(i, carry):
+            minp, maxp, occ, codes = carry
+            row, s, t = votes[i, :, 0], votes[i, :, 1], votes[i, :, 2]
+            ok = row >= 0
+            # pad rows point past the planes, each at its own row, so
+            # their gathers fill and their scatters drop
+            row = jnp.where(ok, row, rows_total + lane)
+            mx = maxp.at[row].get(mode="fill", fill_value=0)
+            mn = minp.at[row].get(mode="fill", fill_value=MAX_DISTANCE)
+            oc = occ.at[row].get(mode="fill", fill_value=False)
+            # surround checks — same plane reads as
+            # TargetArrays.check_surround, surrounded takes priority
+            e1 = s - 1
+            d1 = at(mx, e1 % H)
+            surrounded = (s >= 1) & (d1 != 0) & (e1 + d1 > t)
+            e2 = s + 1
+            d2 = at(mn, e2 % H)
+            surrounds = (d2 != MAX_DISTANCE) & (e2 + d2 < t)
+            dbl = at(oc, t % H)
+            code = (
+                surrounds.astype(jnp.int32) * CODE_SURROUNDS
+                + surrounded.astype(jnp.int32) * CODE_SURROUNDED
+                + dbl.astype(jnp.int32) * CODE_DOUBLE
+            )
+            codes = codes.at[i].set(jnp.where(ok, code, 0))
+            # vectorized apply over every ring position p: recover the
+            # epoch e covering p inside the vote's affected band
+            s, t = s[:, None, None], t[:, None, None]
+            hi = jnp.minimum(t, s + H - 1)
+            off_max = (p - s) % H
+            e_max = s + off_max
+            in_max = off_max <= (hi - s)
+            d_max = jnp.minimum(t - e_max, MAX_DISTANCE - 1)
+            mx = jnp.where(in_max & (d_max > mx), d_max, mx)
+            lo = jnp.maximum(0, s - H + 1)
+            off_min = (s - p) % H
+            e_min = s - off_min
+            in_min = off_min <= (s - lo)
+            d_min = jnp.minimum(t - e_min, MAX_DISTANCE - 1)
+            mn = jnp.where(in_min & (d_min < mn), d_min, mn)
+            oc = oc | (p == t % H)
+            # whole rows, as gathered: a scatter of single elements makes
+            # XLA relayout the whole occupancy plane
+            put = dict(mode="drop", unique_indices=True)
+            maxp = maxp.at[row].set(mx, **put)
+            minp = minp.at[row].set(mn, **put)
+            occ = occ.at[row].set(oc, **put)
+            return minp, maxp, occ, codes
+
+        codes = jnp.zeros(votes.shape[:2], jnp.int32)
+        return jax.lax.fori_loop(0, tiles, tile, (minp, maxp, occ, codes))
+
+    return jax.jit(run, donate_argnums=(0, 1, 2))
+
+
+def _pow2(n: int) -> int:
+    return 1 << max(0, (n - 1).bit_length())
+
+
+def _tiles(rows: np.ndarray, tile: int, floor: int):
+    """Place each vote of a batch (its plane row, in input order) in a
+    tile in which no row repeats: ``(dest, t_eff, tiles, rounds)``.
+
+    A vote's round is the number of earlier votes of its row in the
+    batch; the rounds go in order, each padded to whole tiles of
+    ``t_eff`` rows, and vote ``i`` goes to slot ``dest[i]`` of the
+    tiles laid end to end. Round 0 is the largest, so ``t_eff`` is
+    ``tile``, or round 0 rounded up to a power of two (at least
+    ``floor``) where that is smaller."""
+    n = len(rows)
+    pos = np.arange(n)
+    order = np.argsort(rows, kind="stable")
+    ranked = rows[order]
+    first = np.ones(n, bool)
+    first[1:] = ranked[1:] != ranked[:-1]
+    rnd = np.empty(n, np.int64)
+    rnd[order] = pos - np.maximum.accumulate(np.where(first, pos, 0))
+    per_round = np.bincount(rnd)
+    t_eff = min(tile, max(floor, _pow2(int(per_round[0]))))
+    per_tiles = -(-per_round // t_eff)
+    start = (np.cumsum(per_tiles) - per_tiles) * t_eff
+    by_round = np.argsort(rnd, kind="stable")
+    rank = pos - np.repeat(np.cumsum(per_round) - per_round, per_round)
+    dest = np.empty(n, np.int64)
+    dest[by_round] = start[rnd[by_round]] + rank
+    return dest, t_eff, int(per_tiles.sum()), len(per_round)

@@ -2,6 +2,7 @@
 chunked min/max target arrays, double proposals, chunk persistence, and
 op-pool-ready slashing export (reference test model: slasher/tests)."""
 
+import numpy as np
 import pytest
 
 from lighthouse_tpu.consensus.config import minimal_spec
@@ -12,7 +13,13 @@ from lighthouse_tpu.consensus.types import (
     spec_types,
 )
 from lighthouse_tpu.slasher import DeviceSlasher, Slasher, SlasherConfig
-from lighthouse_tpu.slasher.arrays import MAX_DISTANCE, TargetArrays
+from lighthouse_tpu.slasher.arrays import (
+    CODE_DOUBLE,
+    CODE_SURROUNDED,
+    CODE_SURROUNDS,
+    MAX_DISTANCE,
+    TargetArrays,
+)
 from lighthouse_tpu.store.kv import MemoryStore
 
 SPEC = minimal_spec()
@@ -336,6 +343,7 @@ def _engine_counts():
         "votes": arrays.VOTES_TOTAL.value(),
         "launches": arrays.LAUNCHES_TOTAL.value(),
         "inits": arrays.PLANE_INITS_TOTAL.value(),
+        "tiles": arrays.TILES_TOTAL.value(),
         "miss": monitoring.JIT_CACHE_EVENTS.value(fn="slasher_scan",
                                                   event="miss"),
         "hit": monitoring.JIT_CACHE_EVENTS.value(fn="slasher_scan",
@@ -347,17 +355,22 @@ def _delta(before, after):
     return {k: after[k] - before[k] for k in before}
 
 
+def _engine(monkeypatch, device: str):
+    """A tiny engine: chunk 8, history 64, tiles of 8 rows."""
+    from lighthouse_tpu.slasher.arrays import SurroundEngine
+
+    monkeypatch.setenv("LHTPU_SLASHER_DEVICE", device)
+    return SurroundEngine(validator_chunk_size=8, history_length=64, tile=8)
+
+
 class TestEngineTracing:
     """SurroundEngine's spans and counters, on a tiny engine (chunk 8,
-    history 64): four validator chunks of 8."""
+    history 64, tiles of 8 rows): one program per batch."""
 
     @pytest.fixture
     def engine(self, monkeypatch):
         pytest.importorskip("jax")
-        from lighthouse_tpu.slasher.arrays import SurroundEngine
-
-        monkeypatch.setenv("LHTPU_SLASHER_DEVICE", "1")
-        return SurroundEngine(validator_chunk_size=8, history_length=64)
+        return _engine(monkeypatch, "1")
 
     @pytest.fixture
     def tracing_on(self):
@@ -371,28 +384,33 @@ class TestEngineTracing:
             tracing.set_enabled(prev)
             tracing.clear()
 
-    def test_one_batch_root_with_a_launch_and_wait_per_chunk(
+    def test_one_batch_root_with_one_launch_and_wait(
             self, engine, tracing_on):
-        # chunk 0 holds 9 votes (pad 16), chunks 1-3 hold 8 (pad 8)
-        first = _slot_votes(10, range(32), [(0, 8, 12)])
-        engine.process(first)
+        # 33 votes over 4 chunks, validator 0 twice: round 0 is 4 tiles
+        # of 8, round 1 one tile; then 16 votes over 2 known chunks, then
+        # a vote in a new chunk, which grows the planes
+        engine.process(_slot_votes(10, range(32), [(0, 8, 12)]))
         engine.process(_slot_votes(11, range(8, 24)))
+        engine.process(_slot_votes(12, [40]))
         roots = [r for r in tracing_on.roots()
                  if r.name.startswith("slasher/")]
-        assert [r.name for r in roots] == ["slasher/batch"] * 2
-        assert roots[0].attrs == {"votes": 33, "chunks": 4, "slots": 40}
-        assert roots[1].attrs == {"votes": 16, "chunks": 2, "slots": 16}
-        for root, fresh in zip(roots, (True, False)):
-            kids = [c.name for c in root.children]
-            assert kids == ["slasher/group"] + (
-                ["slasher/prepare"] + ["slasher/init_planes"] * fresh
-                + ["slasher/launch", "slasher/wait"]) * root.attrs["chunks"]
+        assert [r.name for r in roots] == ["slasher/batch"] * 3
+        assert [r.attrs for r in roots] == [
+            {"votes": 33, "chunks": 4, "rounds": 2, "tiles": 5, "slots": 40},
+            {"votes": 16, "chunks": 2, "rounds": 1, "tiles": 2, "slots": 16},
+            {"votes": 1, "chunks": 1, "rounds": 1, "tiles": 1, "slots": 8},
+        ]
+        for root, grows in zip(roots, (True, False, True)):
+            assert [c.name for c in root.children] == (
+                ["slasher/group", "slasher/prepare"]
+                + ["slasher/init_planes"] * grows
+                + ["slasher/launch", "slasher/wait"])
             assert root.size == 1 + sum(c.size for c in root.children)
 
     def test_only_the_batch_spans_feed_the_span_histogram(
             self, engine, tracing_on):
-        # per-chunk spans stay out of lhtpu_span_seconds: its observe
-        # would be most of their cost
+        # the batch's inner steps stay out of lhtpu_span_seconds, as
+        # they did when they ran once per chunk
         def counts():
             return {key[0]: shard.count for key, shard in
                     tracing_on.SPAN_SECONDS._shards.items()
@@ -404,7 +422,7 @@ class TestEngineTracing:
         grew = {k for k in after if after[k] != before.get(k, 0)}
         assert grew == {"slasher/batch", "slasher/group"}
         (root,) = [r for r in tracing_on.roots() if r.name == "slasher/batch"]
-        assert len(root.children) == 1 + 4 * 4
+        assert len(root.children) == 5
 
     def test_counters_advance_once_per_batch_with_tracing_off(self, engine):
         from lighthouse_tpu.common import tracing
@@ -414,27 +432,37 @@ class TestEngineTracing:
             before = _engine_counts()
             engine.process(_slot_votes(10, range(32), [(0, 8, 12)]))
             got = _delta(before, _engine_counts())
-            assert {k: got[k] for k in ("votes", "launches", "inits")} == {
-                "votes": 33, "launches": 4, "inits": 4}
+            assert {k: got[k] for k in ("votes", "launches", "inits",
+                                        "tiles")} == {
+                "votes": 33, "launches": 1, "inits": 4, "tiles": 5}
             before = _engine_counts()
             engine.process(_slot_votes(11, range(8, 24)))
             got = _delta(before, _engine_counts())
-            assert {k: got[k] for k in ("votes", "launches", "inits")} == {
-                "votes": 16, "launches": 2, "inits": 0}
+            assert {k: got[k] for k in ("votes", "launches", "inits",
+                                        "tiles")} == {
+                "votes": 16, "launches": 1, "inits": 0, "tiles": 2}
         finally:
             tracing.set_enabled(prev)
 
     def test_a_compile_is_counted_on_a_new_pad_only(self, engine):
+        # the upload is a power of two of tiles: a new bucket compiles,
+        # more rounds inside a known bucket do not (engines share the
+        # program, so start from an empty cache)
+        engine._program.clear_cache()
         before = _engine_counts()
-        engine.process(_slot_votes(10, range(32)))       # pad 8: compiles
+        engine.process(_slot_votes(10, range(32)))       # 4 tiles: compiles
         got = _delta(before, _engine_counts())
-        assert (got["miss"], got["hit"]) == (1, 3)
-        engine.process(_slot_votes(11, range(32)))       # pad 8 again
+        assert (got["miss"], got["hit"]) == (1, 0)
+        engine.process(_slot_votes(11, range(32)))       # 4 tiles again
         got = _delta(before, _engine_counts())
-        assert (got["miss"], got["hit"]) == (1, 7)
-        engine.process(_slot_votes(12, range(32), [(0, 11, 13)]))  # pad 16
+        assert (got["miss"], got["hit"]) == (1, 1)
+        engine.process(_slot_votes(12, range(32), [(0, 11, 13)]))  # 5 -> 8
         got = _delta(before, _engine_counts())
-        assert (got["miss"], got["hit"]) == (2, 10)
+        assert (got["miss"], got["hit"]) == (2, 1)
+        # three votes of validator 0: three rounds, 6 tiles, bucket 8
+        engine.process(_slot_votes(13, range(32), [(0, 12, 14), (0, 9, 15)]))
+        got = _delta(before, _engine_counts())
+        assert (got["miss"], got["hit"]) == (2, 2)
 
     def test_codes_do_not_depend_on_tracing(self, monkeypatch):
         pytest.importorskip("jax")
@@ -477,23 +505,106 @@ class TestEngineTracing:
         from lighthouse_tpu.common import resilience
         from lighthouse_tpu.slasher import arrays
 
+        batches = [_slot_votes(10, range(32)),
+                   _slot_votes(11, range(8, 24), [(9, 10, 11), (3, 8, 12)])]
+        host = _engine(monkeypatch, "0")
+        want = [host.process(b) for b in batches]
+        monkeypatch.setenv("LHTPU_SLASHER_DEVICE", "1")
         before = arrays.FALLBACKS_TOTAL.items()
+        got = [engine.process(batches[0])]
         monkeypatch.setenv("LHTPU_FAULT_INJECT", "slasher:assert:1")
         resilience.rearm_faults()
         try:
-            engine.process(_slot_votes(10, range(32)))
+            got.append(engine.process(batches[1]))
         finally:
             monkeypatch.delenv("LHTPU_FAULT_INJECT")
             resilience.rearm_faults()
+        assert got == want and any(got[1])
         kinds = engine.report()["fault_kinds"]
-        assert sum(kinds.values()) == 1
+        assert sum(kinds.values()) == 1 and engine.report()["degraded"]
         after = dict((lbl["kind"], v)
                      for lbl, v in arrays.FALLBACKS_TOTAL.items())
         was = dict((lbl["kind"], v) for lbl, v in before)
         (kind,) = kinds
         assert after[kind] - was.get(kind, 0) == 1
-        (root,) = [r for r in tracing_on.roots() if r.name == "slasher/batch"]
-        steps = [c.name for c in root.children[1:]]
-        # the faulted chunk and the three after it run on the host
-        assert steps.count("slasher/host_path") == 4
-        assert "slasher/launch" not in steps
+        roots = [r for r in tracing_on.roots() if r.name == "slasher/batch"]
+        # the faulted batch runs whole on the host, from the vote log
+        assert [c.name for c in roots[-1].children] == [
+            "slasher/group", "slasher/host_path"]
+
+
+class TestEngineRounds:
+    """The device engine against its host path on a tiny engine, where
+    the rounds and tiles of one program matter."""
+
+    @pytest.fixture(autouse=True)
+    def _jax(self):
+        pytest.importorskip("jax")
+
+    def _both(self, monkeypatch, batches):
+        host = _engine(monkeypatch, "0")
+        dev = _engine(monkeypatch, "1")
+        want = [host.process(b) for b in batches]
+        got = []
+        for b in batches:
+            old = dev._planes
+            got.append(dev.process(b))
+            # donated: the planes the program read are gone
+            assert old is None or all(p.is_deleted() for p in old)
+        assert got == want
+        assert dev.report()["fallbacks"] == 0
+        return dev, got
+
+    def test_several_votes_of_one_validator_in_a_batch(self, monkeypatch):
+        # validator 3 votes four times: each vote sees the ones before
+        votes = _slot_votes(10, range(8), [
+            (3, 20, 30), (3, 22, 26), (3, 18, 34), (3, 18, 34)])
+        _, (codes,) = self._both(monkeypatch, [votes])
+        assert codes[8:] == [0, CODE_SURROUNDED, CODE_SURROUNDS,
+                             CODE_SURROUNDS | CODE_DOUBLE]
+
+    def test_a_round_longer_than_a_tile(self, monkeypatch):
+        # round 0 holds 20 validators (3 tiles of 8), round 1 holds 10
+        # (2 tiles), each of them surrounding its round-0 vote
+        votes = _slot_votes(10, range(20), [(v, 8, 12) for v in range(10)])
+        _, (codes,) = self._both(monkeypatch, [votes])
+        assert codes[20:] == [CODE_SURROUNDS] * 10
+
+    def test_sparse_and_growing_chunks_keep_earlier_planes(self, monkeypatch):
+        batches = [
+            [(2, 20, 30), (75, 20, 30)],                 # chunks 0 and 9
+            [(330, 20, 30), (2, 22, 26), (75, 18, 34)],  # chunk 41 grows
+            [(40, 5, 6), (330, 21, 25), (75, 18, 34)],   # chunk 5 grows
+        ]
+        dev, codes = self._both(monkeypatch, batches)
+        assert codes[1][1:] == [CODE_SURROUNDED, CODE_SURROUNDS]
+        assert codes[2][1:] == [CODE_SURROUNDED,
+                                CODE_SURROUNDS | CODE_DOUBLE]
+        assert dev.report()["chunks"] == 4
+        assert dev._planes[0].shape == (4 * 8, 1, 64)
+
+    def test_pad_rows_never_write_row_zero(self, monkeypatch):
+        # validator 0 holds plane row 0; the next batch leaves it out and
+        # pads 9 votes to 2 tiles of 8: a pad row that wrote would mark
+        # target 0 (ring slot 0) in row 0, a double for target 64
+        batches = [[(0, 62, 63)] + _slot_votes(63, range(1, 8)),
+                   _slot_votes(63, range(8, 17)),
+                   [(0, 63, 64)]]
+        dev, codes = self._both(monkeypatch, batches)
+        assert codes[2] == [0]
+        occ = np.asarray(dev._planes[2]).reshape(-1, 64)
+        assert occ[0].tolist() == [p in (0, 63) for p in range(64)]
+
+    def test_tiles_hold_each_row_once_in_round_order(self):
+        from lighthouse_tpu.slasher.arrays import _tiles
+
+        rows = np.array([5, 3, 5, 9, 5, 3, 1, 2, 4, 6, 7, 8, 0])
+        dest, t_eff, tiles, rounds = _tiles(rows, tile=4, floor=2)
+        assert (t_eff, tiles, rounds) == (4, 5, 3)
+        assert sorted(set(dest.tolist())) == sorted(dest.tolist())
+        for k in range(tiles):
+            held = rows[(dest >= k * t_eff) & (dest < (k + 1) * t_eff)]
+            assert len(set(held.tolist())) == len(held)
+        # each row's votes keep their order
+        for r in set(rows.tolist()):
+            assert list(dest[rows == r]) == sorted(dest[rows == r])
