@@ -54,6 +54,10 @@ def build_parser() -> argparse.ArgumentParser:
     bn.add_argument("--metrics-port", type=int, default=0)
     bn.add_argument("--validator-monitor-auto", action="store_true")
     bn.add_argument("--slasher", action="store_true")
+    bn.add_argument("--slasher-devices", type=int, default=0,
+                    help="with --slasher: chips that hold the slasher's "
+                         "min/max-target planes, sharded by validator "
+                         "chunk (0 = on the host, in the database)")
     bn.add_argument("--interop-validators", type=int, default=64)
     bn.add_argument("--checkpoint-sync-url", default=None)
     bn.add_argument("--boot-nodes", default=None,
@@ -215,6 +219,7 @@ def run_bn(args) -> int:
         metrics_port=args.metrics_port,
         validator_monitor_auto=args.validator_monitor_auto,
         slasher_enabled=args.slasher,
+        slasher_devices=args.slasher_devices,
         backend=args.backend,
         manual_clock=args.slots > 0,
     )

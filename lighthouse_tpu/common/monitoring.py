@@ -6,8 +6,9 @@ an RSS reader (``/proc/self/status`` VmRSS with a
 ``resource.getrusage`` fallback) behind the
 ``process_resident_memory_bytes`` gauge, the one compile counter of
 every jitted device program (``bls_jit_cache_events_total``, through
-:func:`jit_cache_probe`), and a jit-cache entry estimate behind
-``bls_jit_cache_entries``."""
+:func:`jit_cache_probe`), a jit-cache entry estimate behind
+``bls_jit_cache_entries``, and the slasher engine's per-shard vote
+counter ``slasher_shard_votes_total``."""
 
 from __future__ import annotations
 
@@ -64,6 +65,15 @@ JIT_CACHE_EVENTS = REGISTRY.counter(
     "bls_jit_cache_events_total",
     "Jitted device-program dispatches by compile-cache outcome",
     ("fn", "event"),
+)
+
+
+#: votes each shard of the slasher engine's device planes classified
+#: (``slasher/arrays.py`` SurroundEngine), advanced once a launched batch
+SLASHER_SHARD_VOTES = REGISTRY.counter(
+    "slasher_shard_votes_total",
+    "Votes classified by each shard of the surround engine's device planes",
+    ("shard",),
 )
 
 

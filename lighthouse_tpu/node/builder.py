@@ -22,7 +22,7 @@ from ..common.task_executor import TaskExecutor
 from ..consensus.config import ChainSpec, minimal_spec
 from ..consensus.genesis import interop_genesis_state, interop_keypairs
 from ..network import NetworkService
-from ..slasher import Slasher
+from ..slasher import DeviceSlasher, Slasher, SlasherConfig
 from ..store.hot_cold import HotColdDB, StoreConfig
 from ..store.kv import MemoryStore
 
@@ -41,6 +41,9 @@ class ClientConfig:
     metrics_enabled: bool = False
     metrics_port: int = 0  # 0 = ephemeral (tests); set for scrape targets
     slasher_enabled: bool = False
+    # >0 runs the slasher's surround/double-vote planes on that many
+    # chips (DeviceSlasher); 0 keeps them in the KV store (Slasher)
+    slasher_devices: int = 0
     validator_monitor_auto: bool = False  # watch all validators
     validator_monitor_indices: tuple = ()  # or specific indices
     attestation_batch_size: int = 1024
@@ -289,7 +292,11 @@ class ClientBuilder:
             )
 
         slasher = None
-        if cfg.slasher_enabled:
+        if cfg.slasher_enabled and cfg.slasher_devices:
+            slasher = DeviceSlasher(
+                chain.types, SlasherConfig(devices=cfg.slasher_devices),
+                db=store_backend)
+        elif cfg.slasher_enabled:
             slasher = Slasher(chain.types, db=store_backend)
 
         api = BeaconApi(chain, network=network)

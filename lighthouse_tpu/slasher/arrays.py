@@ -203,28 +203,38 @@ class SurroundEngine:
     """Batched surround/double-vote detection on device (ISSUE 17).
 
     The per-vote state TargetArrays keeps in compressed KV chunks lives
-    here as three resident device planes of ``slots × chunk`` rows of
-    ``history`` epochs — min distances (int32, default MAX_DISTANCE), max
-    distances (int32, default 0) and ring occupancy for double-vote
-    candidates (bool), each row held as ``[history / 128, 128]``. A
-    validator chunk gets a slot the first time it receives a vote
-    (``_slot``, a host table), so the planes grow with the chunks
-    touched, and validator ``v`` owns row ``slot[v // chunk] × chunk +
-    v % chunk`` of each plane.
+    here as three resident device planes — min distances (int32,
+    default MAX_DISTANCE), max distances (int32, default 0) and ring
+    occupancy for double-vote candidates (bool) — each
+    ``[devices, rows, history / 128, 128]``, sharded on the leading axis
+    over a one-axis mesh of the first ``devices`` chips, so a plane row
+    is whole (8, 128) tiles. A validator chunk gets a slot the first
+    time it receives a vote (``_slot``, a host table, slots handed out
+    in first-seen order), so the planes grow with the chunks touched.
+    Slot ``k`` lives on shard ``k % devices`` at local slot
+    ``k // devices``, and validator ``v`` owns row ``local × chunk + v %
+    chunk`` of its shard; set-up's votes, which touch every chunk in
+    chunk order, spread the chunks evenly. A chip holds its own rows
+    only, and a plane grows along its local row axis, on every shard at
+    once, so no row ever moves between chips.
 
-    One ``process`` call is one device program: one upload, one launch,
-    one read-back. Only the order of one validator's votes matters (a
-    vote reads and writes its own row alone), so a vote's *round* is
-    the number of earlier votes of its validator in the batch, and the
-    votes go round after round in tiles of ``T_eff`` rows, no row twice
-    in a tile. A ``fori_loop`` walks the tiles: each gathers its rows of
-    the planes, computes the codes and the banded updates vectorized
-    across the epoch ring, and scatters the rows back. The planes are
-    donated, so the update is in place. ``T_eff`` is ``tile`` or, for
-    smaller batches, the largest round rounded up to a power of two (at
-    least ``pad_floor``); the tiles uploaded are a power of two, so a
-    batch compiles nothing new unless its (tiles, ``T_eff``) bucket or
-    the plane size is new.
+    One ``process`` call is one device program across the chips: one
+    upload, one launch, one read-back. Only the order of one
+    validator's votes matters (a vote reads and writes its own row
+    alone), so a vote's *round* is the number of earlier votes of its
+    validator in the batch, and each shard's votes go round after round
+    in tiles of ``T_eff`` rows, no row twice in a tile. Under
+    ``shard_map`` every chip walks its own tiles with a ``fori_loop``:
+    each gathers its rows of the local planes, computes the codes and
+    the banded updates vectorized across the epoch ring, and scatters
+    the rows back; no collective, since no vote reads another shard's
+    rows. The planes are donated, so the update is in place. ``T_eff``
+    is ``tile`` or, for smaller batches, the busiest shard's largest
+    round rounded up to a power of two (at least ``pad_floor``); the
+    tiles uploaded per shard are the busiest shard's count rounded up
+    to a power of two, so a batch compiles nothing new unless its
+    (tiles, ``T_eff``) bucket or the plane size is new. One chip is the
+    case ``devices=1`` of the same program.
 
     Verdict codes are bits (CODE_SURROUNDS / CODE_SURROUNDED /
     CODE_DOUBLE); the double bit is only a candidate — the caller
@@ -242,7 +252,10 @@ class SurroundEngine:
 
     def __init__(self, validator_chunk_size: int | None = None,
                  history_length: int | None = None, pad_floor: int = 8,
-                 tile: int = 512):
+                 tile: int = 512, devices: int = 1):
+        if devices < 1:
+            raise ValueError(f"devices must be at least 1, not {devices}")
+        self.shards = int(devices)
         self.validator_chunk_size = (
             validator_chunk_size if validator_chunk_size is not None
             else int(knobs.knob("LHTPU_SLASHER_CHUNK"))
@@ -255,28 +268,39 @@ class SurroundEngine:
         self.tile = max(self.pad_floor, tile)
         forced = knobs.knob("LHTPU_SLASHER_DEVICE")
         self._jax = None
-        self._jnp = None
         self._program = None
+        self._grow = None
+        self._sharding = None
         if forced == "0":
             self.device = False
         else:
             try:
                 import jax
-                import jax.numpy as jnp
 
-                self._jax, self._jnp = jax, jnp
-                self._program = _plane_program()
+                self._jax = jax
+                chips = jax.devices()
                 self.device = True
             except Exception:
                 if forced == "1":
                     raise
                 self.device = False
+        if self.device:
+            if len(chips) < self.shards:
+                raise ValueError(f"the slasher's planes need {self.shards} "
+                                 f"devices, JAX sees {len(chips)}")
+            from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+            mesh = Mesh(np.asarray(chips[:self.shards]), (SHARD_AXIS,))
+            self._sharding = NamedSharding(mesh, PartitionSpec(SHARD_AXIS))
+            self._program = _plane_program(mesh)
+            self._grow = _grow_program(mesh)
         self.degraded = False       # sticky host fallback after a fault
         self.fallbacks = 0
         self.fault_kinds: dict[str, int] = {}
         self.processed = 0
         self._slot = np.full(0, -1, np.int64)     # chunk -> slot, -1 none
         self._slots_used = 0                      # slots handed out
+        self._slots_made = 0                      # slots with plane rows
         self._planes = None                       # (min, max, occ) planes
         self._host: dict[int, tuple] = {}         # chunk -> host mirror
         self._log: list[np.ndarray] = []          # each batch's votes
@@ -289,25 +313,31 @@ class SurroundEngine:
             if not votes:
                 return []
             self.processed += len(votes)
+            C = self.validator_chunk_size
             with tracing.span("slasher/group"):
                 arr = np.fromiter(itertools.chain.from_iterable(votes),
                                   np.int64, 3 * len(votes)).reshape(-1, 3)
-                chunk, vi = np.divmod(arr[:, 0], self.validator_chunk_size)
+                chunk, vi = np.divmod(arr[:, 0], C)
                 slot = self._assign_slots(chunk)
-                rows = slot * self.validator_chunk_size + vi
-                dest, t_eff, tiles, rounds = _tiles(rows, self.tile,
-                                                    self.pad_floor)
+                with tracing.span("slasher/route", mirror=False):
+                    local, shard = np.divmod(slot, self.shards)
+                    per_shard = np.bincount(shard, minlength=self.shards)
+                    dest, t_eff, tiles, rounds = _tiles(
+                        slot * C + vi, self.tile, self.pad_floor, shard,
+                        self.shards)
             batch.set(chunks=int(np.count_nonzero(np.bincount(slot))),
-                      rounds=rounds)
+                      rounds=rounds, shards=self.shards,
+                      shard_votes_max=int(per_shard.max()))
             launched = False
             fault = None
             try:
                 resilience.maybe_inject("slasher")
                 if self.device and not self.degraded:
-                    codes = self._process_device(arr, rows, dest, t_eff,
-                                                 tiles)
+                    codes = self._process_device(
+                        arr, shard, local * C + vi, dest, t_eff, tiles)
                     launched = True
-                    batch.set(tiles=tiles, slots=tiles * t_eff)
+                    batch.set(tiles=tiles,
+                              slots=self.shards * tiles * t_eff)
                 else:
                     with tracing.span("slasher/host_path", mirror=False):
                         codes = self._process_host(arr)
@@ -324,7 +354,9 @@ class SurroundEngine:
             VOTES_TOTAL.inc(len(votes))
             if launched:
                 LAUNCHES_TOTAL.inc()
-                TILES_TOTAL.inc(tiles)
+                TILES_TOTAL.inc(self.shards * tiles)
+                for k, n in enumerate(per_shard.tolist()):
+                    monitoring.SLASHER_SHARD_VOTES.inc(n, shard=str(k))
             if fault is not None:
                 FALLBACKS_TOTAL.inc(kind=fault)
         return codes
@@ -337,6 +369,7 @@ class SurroundEngine:
             "fault_kinds": dict(self.fault_kinds),
             "votes": self.processed,
             "chunks": self._slots_used,
+            "shards": self.shards,
         }
 
     def _assign_slots(self, chunk: np.ndarray) -> np.ndarray:
@@ -359,42 +392,50 @@ class SurroundEngine:
 
     # ---------------------------------------------------------- device path
     def _grow_planes(self) -> int:
-        """Give every slot its plane rows; returns the chunks made
-        fresh. One plane at a time, each old buffer released before the
-        next plane grows, so the peak is one plane's old and new copy."""
-        jnp = self._jnp
-        have = 0 if self._planes is None else (
-            self._planes[0].shape[0] // self.validator_chunk_size)
-        if have == self._slots_used:
+        """Give every slot its plane rows; returns the chunks given rows.
+        Each shard grows along its local row axis to ``ceil(slots /
+        devices)`` chunks, one plane at a time, each old buffer released
+        before the next plane grows, so the peak is one plane's old and
+        new copy."""
+        fresh = self._slots_used - self._slots_made
+        if not fresh:
             return 0
+        C, N, H = self.validator_chunk_size, self.shards, self.history_length
+        need = -(-self._slots_used // N) * C
+        have = 0 if self._planes is None else self._planes[0].shape[1]
+        self._slots_made = self._slots_used
+        if need == have:
+            return fresh
         with tracing.span("slasher/init_planes", mirror=False):
             planes, self._planes = list(self._planes or ()), None
-            H = self.history_length
             lanes = 128 if H % 128 == 0 else H
-            shape = ((self._slots_used - have) * self.validator_chunk_size,
-                     H // lanes, lanes)
             for k, (fill, dtype) in enumerate(
-                    ((MAX_DISTANCE, jnp.int32), (0, jnp.int32),
-                     (False, jnp.bool_))):
+                    ((MAX_DISTANCE, np.int32), (0, np.int32),
+                     (False, np.bool_))):
                 if k < len(planes):
                     old = planes[k]
-                    planes[k] = jnp.concatenate(
-                        (old, jnp.full(shape, fill, dtype=dtype)))
-                    old.delete()
                 else:
-                    planes.append(jnp.full(shape, fill, dtype=dtype))
+                    old = self._jax.device_put(
+                        np.zeros((N, 0, H // lanes, lanes), dtype),
+                        self._sharding)
+                    planes.append(old)
+                planes[k] = self._grow(old, need - have, fill)
+                old.delete()
             self._planes = tuple(planes)
-        return self._slots_used - have
+        return fresh
 
-    def _process_device(self, arr, rows, dest, t_eff: int,
+    def _process_device(self, arr, shard, rows, dest, t_eff: int,
                         tiles: int) -> list[int]:
         """The batch through one plane program; codes in input order."""
         with tracing.span("slasher/prepare", mirror=False):
-            buf = np.zeros((_pow2(tiles) * t_eff, 3), np.int32)
+            run = _pow2(tiles) * t_eff
+            at = shard * run + dest
+            buf = np.zeros((self.shards * run, 3), np.int32)
             buf[:, 0] = -1
-            buf[dest, 0] = rows
-            buf[dest, 1:] = arr[:, 1:]
-            votes = self._jax.device_put(buf.reshape(-1, t_eff, 3))
+            buf[at, 0] = rows
+            buf[at, 1:] = arr[:, 1:]
+            votes = self._jax.device_put(
+                buf.reshape(self.shards, -1, t_eff, 3), self._sharding)
         fresh = self._grow_planes()
         probe = monitoring.jit_cache_probe(self._program, "slasher_scan")
         with tracing.span("slasher/launch", mirror=False):
@@ -406,7 +447,7 @@ class SurroundEngine:
             PLANE_INITS_TOTAL.inc(fresh)
         with tracing.span("slasher/wait", mirror=False):
             codes = self._jax.device_get(codes)
-        return codes.reshape(-1)[dest].tolist()
+        return codes.reshape(-1)[at].tolist()
 
     # ------------------------------------------------------------ host path
     def _fresh_host(self):
@@ -462,12 +503,19 @@ class SurroundEngine:
         return code
 
 
+#: the one mesh axis the engine's planes are sharded on
+SHARD_AXIS = "shard"
+
+
 @functools.cache
-def _plane_program():
+def _plane_program(mesh):
     """The jitted program that steps one batch's tiles over the donated
-    planes, shared by every engine (the history is the planes' width)."""
+    planes on every chip of ``mesh``, shared by the engines on those
+    chips (the history is the planes' width). Each chip runs the tile
+    loop on its own shard; nothing crosses chips."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import PartitionSpec
 
     def run(minp, maxp, occ, votes, tiles):
         # a plane row is [groups, lanes]: on the TPU a row is then whole
@@ -535,23 +583,58 @@ def _plane_program():
         codes = jnp.zeros(votes.shape[:2], jnp.int32)
         return jax.lax.fori_loop(0, tiles, tile, (minp, maxp, occ, codes))
 
-    return jax.jit(run, donate_argnums=(0, 1, 2))
+    def shard(minp, maxp, occ, votes, tiles):
+        """One chip's part: its planes and tiles, without the shard
+        axis (a view, so the donated planes stay in place)."""
+        out = run(minp[0], maxp[0], occ[0], votes[0], tiles)
+        return tuple(x[None] for x in out)
+
+    spec = PartitionSpec(SHARD_AXIS)
+    return jax.jit(jax.shard_map(shard, mesh=mesh,
+                                 in_specs=(spec,) * 4 + (PartitionSpec(),),
+                                 out_specs=(spec,) * 4, check_vma=False),
+                   donate_argnums=(0, 1, 2))
+
+
+@functools.cache
+def _grow_program(mesh):
+    """``grow(plane, rows, fill)``: the plane with ``rows`` more rows
+    of ``fill`` on every shard, appended along the local row axis, so
+    no row moves between chips."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec
+
+    def grow(plane, rows, fill):
+        def shard(old):
+            new = jnp.full((1, rows) + old.shape[2:], fill, old.dtype)
+            return jnp.concatenate((old, new), axis=1)
+
+        spec = PartitionSpec(SHARD_AXIS)
+        return jax.shard_map(shard, mesh=mesh, in_specs=spec,
+                             out_specs=spec)(plane)
+
+    return jax.jit(grow, static_argnums=(1, 2))
 
 
 def _pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
-def _tiles(rows: np.ndarray, tile: int, floor: int):
-    """Place each vote of a batch (its plane row, in input order) in a
-    tile in which no row repeats: ``(dest, t_eff, tiles, rounds)``.
+def _tiles(rows: np.ndarray, tile: int, floor: int, shard: np.ndarray,
+           shards: int):
+    """Place each vote of a batch (its plane row, unique across shards,
+    in input order) in a tile of its shard in which no row repeats:
+    ``(dest, t_eff, tiles, rounds)``.
 
     A vote's round is the number of earlier votes of its row in the
-    batch; the rounds go in order, each padded to whole tiles of
-    ``t_eff`` rows, and vote ``i`` goes to slot ``dest[i]`` of the
-    tiles laid end to end. Round 0 is the largest, so ``t_eff`` is
-    ``tile``, or round 0 rounded up to a power of two (at least
-    ``floor``) where that is smaller."""
+    batch; on each shard the rounds go in order, each padded to whole
+    tiles of ``t_eff`` rows, and vote ``i`` goes to slot ``dest[i]`` of
+    its shard's tiles laid end to end. Round 0 is the largest, so
+    ``t_eff`` is ``tile``, or the busiest shard's round 0 rounded up to
+    a power of two (at least ``floor``) where that is smaller; ``tiles``
+    is the busiest shard's count; ``shard`` gives each vote's shard, of
+    ``shards``."""
     n = len(rows)
     pos = np.arange(n)
     order = np.argsort(rows, kind="stable")
@@ -560,12 +643,15 @@ def _tiles(rows: np.ndarray, tile: int, floor: int):
     first[1:] = ranked[1:] != ranked[:-1]
     rnd = np.empty(n, np.int64)
     rnd[order] = pos - np.maximum.accumulate(np.where(first, pos, 0))
-    per_round = np.bincount(rnd)
-    t_eff = min(tile, max(floor, _pow2(int(per_round[0]))))
-    per_tiles = -(-per_round // t_eff)
-    start = (np.cumsum(per_tiles) - per_tiles) * t_eff
-    by_round = np.argsort(rnd, kind="stable")
-    rank = pos - np.repeat(np.cumsum(per_round) - per_round, per_round)
+    rounds = int(rnd.max()) + 1
+    # per (shard, round): its votes, its tiles, its first slot
+    group = shard * rounds + rnd
+    per_group = np.bincount(group, minlength=shards * rounds)
+    t_eff = min(tile, max(floor, _pow2(int(per_group[::rounds].max()))))
+    per_tiles = (-(-per_group // t_eff)).reshape(shards, rounds)
+    start = (np.cumsum(per_tiles, axis=1) - per_tiles).ravel() * t_eff
+    by_group = np.argsort(group, kind="stable")
+    rank = pos - np.repeat(np.cumsum(per_group) - per_group, per_group)
     dest = np.empty(n, np.int64)
-    dest[by_round] = start[rnd[by_round]] + rank
-    return dest, t_eff, int(per_tiles.sum()), len(per_round)
+    dest[by_group] = start[group[by_group]] + rank
+    return dest, t_eff, int(per_tiles.sum(axis=1).max()), rounds

@@ -40,6 +40,9 @@ class SlasherConfig:
     validator_chunk_size: int = 256
     history_length: int = 4096
     slot_offset: float = 0.5
+    #: chips holding DeviceSlasher's planes, each a 1/devices share of
+    #: the validator chunks
+    devices: int = 1
 
 
 @dataclass
@@ -237,7 +240,11 @@ class DeviceSlasher(Slasher):
 
     The KV side — attestation records, the (validator, target) root
     map, proposal keys, slashing containers — is inherited unchanged;
-    only the per-vote plane scan moves to device. Findings are
+    only the per-vote plane scan moves to device. The engine holds the
+    planes across ``config.devices`` chips, sharded by validator chunk:
+    a chunk's slot ``k`` lives on chip ``k % devices``, and each batch
+    runs as one program on every chip, each chip stepping the votes
+    routed to its own chunks. Findings are
     materialized in the host's exact per-vote order (double before
     surround, map written only when absent), so the output is
     bit-identical to the host ``Slasher`` oracle on any input, and the
@@ -250,6 +257,7 @@ class DeviceSlasher(Slasher):
         self.engine = engine or SurroundEngine(
             validator_chunk_size=self.config.validator_chunk_size,
             history_length=self.config.history_length,
+            devices=self.config.devices,
         )
 
     def process_queued(self, current_epoch: int) -> list:

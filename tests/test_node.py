@@ -88,6 +88,32 @@ class TestClientBuilder:
         assert n2.slasher.stats["blocks"] >= 1  # block reached the slasher
         n1.stop(), n2.stop()
 
+    def test_slasher_devices_build_the_device_slasher(self):
+        from lighthouse_tpu.slasher import DeviceSlasher, Slasher
+
+        spec = minimal_spec()
+        args = build_parser().parse_args(
+            ["bn", "--slasher", "--slasher-devices", "2"])
+        assert args.slasher and args.slasher_devices == 2
+        assert build_parser().parse_args(["bn"]).slasher_devices == 0
+        node = (
+            ClientBuilder(ClientConfig(validator_count=16,
+                                       slasher_enabled=True,
+                                       slasher_devices=2), spec)
+            .memory_store().interop_genesis().build()
+        )
+        assert isinstance(node.slasher, DeviceSlasher)
+        assert node.slasher.config.devices == 2
+        assert node.slasher.engine.shards == 2
+        node.stop()
+        node = (
+            ClientBuilder(ClientConfig(validator_count=16,
+                                       slasher_enabled=True), spec)
+            .memory_store().interop_genesis().build()
+        )
+        assert type(node.slasher) is Slasher
+        node.stop()
+
     def test_checkpoint_sync_boot(self):
         """New node boots from a remote node's finalized/head state and
         continues from there (builder.rs:252-365)."""

@@ -329,6 +329,25 @@ class TestDeviceSlasher:
         assert rep["degraded"] is True
         assert rep["fault_kinds"]
 
+    def test_four_devices_match_the_host_slasher(self, monkeypatch):
+        pytest.importorskip("jax")
+        monkeypatch.setenv("LHTPU_SLASHER_DEVICE", "1")
+        cfg = SlasherConfig(chunk_size=4, validator_chunk_size=8,
+                            history_length=64, devices=4)
+        for seed in (1, 42):
+            batches = _adversarial_batches(seed, batches=5)
+            host, dev = Slasher(T, config=cfg), DeviceSlasher(T, config=cfg)
+            for batch in batches:
+                for att in batch:
+                    host.accept_attestation(att)
+                    dev.accept_attestation(att)
+                assert (_fingerprint(dev.process_queued(64))
+                        == _fingerprint(host.process_queued(64)))
+            rep = dev.engine.report()
+            assert rep["shards"] == 4 and rep["device"]
+            assert rep["fallbacks"] == 0
+            assert dev.engine._planes[0].shape[0] == 4
+
 
 def _slot_votes(epoch, validators, extra=()):
     """One vote (epoch - 1 -> epoch) per validator, then ``extra``."""
@@ -395,10 +414,14 @@ class TestEngineTracing:
         roots = [r for r in tracing_on.roots()
                  if r.name.startswith("slasher/")]
         assert [r.name for r in roots] == ["slasher/batch"] * 3
+        one = {"shards": 1}
         assert [r.attrs for r in roots] == [
-            {"votes": 33, "chunks": 4, "rounds": 2, "tiles": 5, "slots": 40},
-            {"votes": 16, "chunks": 2, "rounds": 1, "tiles": 2, "slots": 16},
-            {"votes": 1, "chunks": 1, "rounds": 1, "tiles": 1, "slots": 8},
+            {"votes": 33, "chunks": 4, "rounds": 2, "tiles": 5, "slots": 40,
+             "shard_votes_max": 33, **one},
+            {"votes": 16, "chunks": 2, "rounds": 1, "tiles": 2, "slots": 16,
+             "shard_votes_max": 16, **one},
+            {"votes": 1, "chunks": 1, "rounds": 1, "tiles": 1, "slots": 8,
+             "shard_votes_max": 1, **one},
         ]
         for root, grows in zip(roots, (True, False, True)):
             assert [c.name for c in root.children] == (
@@ -581,7 +604,7 @@ class TestEngineRounds:
         assert codes[2][1:] == [CODE_SURROUNDED,
                                 CODE_SURROUNDS | CODE_DOUBLE]
         assert dev.report()["chunks"] == 4
-        assert dev._planes[0].shape == (4 * 8, 1, 64)
+        assert dev._planes[0].shape == (1, 4 * 8, 1, 64)
 
     def test_pad_rows_never_write_row_zero(self, monkeypatch):
         # validator 0 holds plane row 0; the next batch leaves it out and
@@ -595,16 +618,203 @@ class TestEngineRounds:
         occ = np.asarray(dev._planes[2]).reshape(-1, 64)
         assert occ[0].tolist() == [p in (0, 63) for p in range(64)]
 
-    def test_tiles_hold_each_row_once_in_round_order(self):
+    @pytest.mark.parametrize("shards", [1, 2])
+    def test_tiles_hold_each_row_once_in_round_order(self, shards):
         from lighthouse_tpu.slasher.arrays import _tiles
 
-        rows = np.array([5, 3, 5, 9, 5, 3, 1, 2, 4, 6, 7, 8, 0])
-        dest, t_eff, tiles, rounds = _tiles(rows, tile=4, floor=2)
-        assert (t_eff, tiles, rounds) == (4, 5, 3)
-        assert sorted(set(dest.tolist())) == sorted(dest.tolist())
-        for k in range(tiles):
-            held = rows[(dest >= k * t_eff) & (dest < (k + 1) * t_eff)]
-            assert len(set(held.tolist())) == len(held)
+        rows = np.array([5, 3, 5, 9, 5, 3, 1, 2, 4, 6, 7, 8, 0, 11, 13])
+        shard = rows % shards
+        dest, t_eff, tiles, rounds = _tiles(rows, 4, 2, shard, shards)
+        # one shard: round 0 is 12 rows (3 tiles), then 2 and 1; two
+        # shards: shard 1 holds 5 x3, 3 x2, 9, 1, 7, 11, 13, its round 0
+        # 7 rows (2 tiles), then 2 and 1
+        assert (t_eff, tiles, rounds) == (4, 5 if shards == 1 else 4, 3)
+        for k in range(shards):
+            mine = shard == k
+            assert len(set(dest[mine].tolist())) == mine.sum()
+            assert dest[mine].max() < tiles * t_eff
+            for i in range(tiles):
+                held = rows[mine & (dest // t_eff == i)]
+                assert len(set(held.tolist())) == len(held)
         # each row's votes keep their order
         for r in set(rows.tolist()):
             assert list(dest[rows == r]) == sorted(dest[rows == r])
+
+def _plain_codes(batches):
+    """Each vote's code from every earlier vote of its validator, one
+    vote at a time (the votes stay inside one history)."""
+    seen: dict = {}
+    out = []
+    for batch in batches:
+        codes = []
+        for v, src, tgt in batch:
+            code = 0
+            for ps, pt in seen.get(v, ()):
+                if ps > src and pt < tgt:
+                    code |= CODE_SURROUNDS
+                if ps < src and pt > tgt:
+                    code |= CODE_SURROUNDED
+                if pt == tgt:
+                    code |= CODE_DOUBLE
+            seen.setdefault(v, []).append((src, tgt))
+            codes.append(code)
+        out.append(codes)
+    return out
+
+
+def _seeded_batches(seed, batches=4, votes=150, validators=120):
+    """Seeded votes inside a 64-epoch history: many validators vote two
+    or three times in a batch (second and third rounds), and later
+    batches bring validators of new chunks."""
+    r = np.random.default_rng(seed)
+    out = []
+    for k in range(batches):
+        v = r.integers(0, validators + 40 * k, votes)
+        v[: votes // 10] = v[votes // 10: votes // 5]   # second rounds
+        v[: votes // 30] = v[votes // 5: votes // 5 + votes // 30]
+        src = r.integers(10, 40, votes)
+        tgt = src + r.integers(1, 12, votes)
+        out.append([tuple(x) for x in
+                    np.stack([v, src, tgt], axis=1).tolist()])
+    return out
+
+
+class TestEngineShards:
+    """The engine with its planes sharded over 1, 2 and 4 of the forced
+    CPU devices: one program a batch across the shards, the same codes
+    as the host path and a plain replay."""
+
+    @pytest.fixture(autouse=True)
+    def _devices(self, monkeypatch):
+        jax = pytest.importorskip("jax")
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 forced host devices")
+        monkeypatch.setenv("LHTPU_SLASHER_DEVICE", "1")
+
+    @staticmethod
+    def _engine(devices):
+        from lighthouse_tpu.slasher.arrays import SurroundEngine
+
+        return SurroundEngine(validator_chunk_size=8, history_length=64,
+                              tile=8, devices=devices)
+
+    @pytest.mark.parametrize("seed", [3, 2**31 + 25])
+    def test_codes_equal_for_1_2_and_4_devices(self, monkeypatch, seed):
+        batches = _seeded_batches(seed)
+        want = _plain_codes(batches)
+        assert any(c & CODE_DOUBLE for b in want for c in b)
+        assert any(c & (CODE_SURROUNDS | CODE_SURROUNDED)
+                   for b in want for c in b)
+        monkeypatch.setenv("LHTPU_SLASHER_DEVICE", "0")
+        host = self._engine(1)
+        assert [host.process(b) for b in batches] == want
+        monkeypatch.setenv("LHTPU_SLASHER_DEVICE", "1")
+        for n in (1, 2, 4):
+            eng = self._engine(n)
+            assert [eng.process(b) for b in batches] == want, n
+            rep = eng.report()
+            assert rep["device"] and rep["fallbacks"] == 0
+            assert rep["shards"] == n
+
+    def test_planes_grow_per_shard_without_moving_rows(self):
+        # chunks 0..4 then 5..9 then 10: slot k on shard k % 4; each
+        # call that brings new chunks grows every shard's local rows
+        eng = self._engine(4)
+        batches = [
+            [(8 * c + 1, 20, 30) for c in range(5)],
+            [(8 * c + 1, 22, 26) for c in range(10)],
+            [(81, 21, 25), (1, 18, 34), (41, 19, 31), (73, 22, 26)],
+        ]
+        got = [eng.process(b) for b in batches]
+        assert got == _plain_codes(batches)
+        assert got[1][:5] == [CODE_SURROUNDED] * 5
+        min_plane = eng._planes[0]
+        assert min_plane.shape == (4, 3 * 8, 1, 64)
+        assert min_plane.sharding.spec[0] == "shard"
+        assert len(min_plane.sharding.device_set) == 4
+        # validator 41 (chunk 5, slot 5) lives on shard 1, local slot 1,
+        # validator 9 (chunk 1, slot 1) on shard 1, local slot 0
+        maxp = np.asarray(eng._planes[1])
+        assert maxp[1, 8 + 1].reshape(-1)[30] == 1   # (19, 31) at 30
+        assert maxp[1, 1].reshape(-1)[22] == 8       # (20, 30) at 22
+
+    def test_a_fault_falls_back_with_the_same_codes(self, monkeypatch):
+        from lighthouse_tpu.common import resilience
+
+        batches = _seeded_batches(11)
+        want = _plain_codes(batches)
+        eng = self._engine(4)
+        got = [eng.process(batches[0])]
+        monkeypatch.setenv("LHTPU_FAULT_INJECT", "slasher:assert:1")
+        resilience.rearm_faults()
+        try:
+            got.append(eng.process(batches[1]))
+        finally:
+            monkeypatch.delenv("LHTPU_FAULT_INJECT")
+            resilience.rearm_faults()
+        got += [eng.process(b) for b in batches[2:]]
+        assert got == want
+        rep = eng.report()
+        assert rep["degraded"] and rep["fallbacks"] == 1
+
+    def test_one_launch_a_batch_across_the_shards(self):
+        from lighthouse_tpu.common import tracing
+
+        eng = self._engine(4)
+        prev = tracing.set_enabled(False)
+        try:
+            for b in _seeded_batches(5):
+                before = _engine_counts()
+                eng.process(b)
+                got = _delta(before, _engine_counts())
+                assert got["launches"] == 1
+                assert got["miss"] + got["hit"] == 1
+                assert got["votes"] == len(b)
+                assert got["tiles"] % 4 == 0
+        finally:
+            tracing.set_enabled(prev)
+
+    def test_shard_counter_and_batch_attributes(self):
+        from lighthouse_tpu.common import monitoring, tracing
+
+        def shard_votes():
+            return {k: monitoring.SLASHER_SHARD_VOTES.value(shard=str(k))
+                    for k in range(4)}
+
+        eng = self._engine(4)
+        # chunks 0..3 get slots 0..3, one per shard; then 3 votes in
+        # chunk 0, one in chunk 2 (validator 17 twice: two rounds)
+        first = [(8 * c, 20, 21) for c in range(4)]
+        second = [(1, 21, 22), (2, 21, 22), (3, 21, 22), (17, 21, 22),
+                  (17, 20, 23)]
+        for on in (False, True):
+            prev = tracing.set_enabled(on)
+            tracing.clear()
+            try:
+                before = shard_votes()
+                eng.process(first if not on else
+                            [(v, s + 10, t + 10) for v, s, t in first])
+                eng.process(second if not on else
+                            [(v, s + 10, t + 10) for v, s, t in second])
+                after = shard_votes()
+                roots = [r for r in tracing.roots()
+                         if r.name == "slasher/batch"]
+            finally:
+                tracing.set_enabled(prev)
+                tracing.clear()
+            assert {k: after[k] - before[k] for k in after} == {
+                0: 4, 1: 1, 2: 3, 3: 1}
+        assert [(r.attrs["shards"], r.attrs["shard_votes_max"],
+                 r.attrs["rounds"], r.attrs["tiles"], r.attrs["slots"])
+                for r in roots] == [(4, 1, 1, 1, 32), (4, 3, 2, 2, 64)]
+        group = roots[1].children[0]
+        assert group.name == "slasher/group"
+        assert [c.name for c in group.children] == ["slasher/route"]
+
+    def test_more_devices_than_jax_sees_is_an_error(self):
+        import jax
+
+        with pytest.raises(ValueError, match="devices"):
+            self._engine(len(jax.devices()) + 1)
+        with pytest.raises(ValueError):
+            self._engine(0)
